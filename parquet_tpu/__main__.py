@@ -262,11 +262,13 @@ def _serve_cmd(args) -> int:
     import threading
 
     from .serve import Server, load_config
+    from .utils.compile_cache import setup_compile_cache
 
     if not args.config:
         print("parquet_tpu: serve requires --config serve.json",
               file=sys.stderr)
         return 1
+    setup_compile_cache()
     try:
         config = load_config(args.config)
         # None = not passed -> the config's host wins; an explicit

@@ -212,8 +212,9 @@ declare("PARQUET_TPU_LOCKCHECK_REPORT", "str", "",
 
 # ----------------------------------------------------------- device / native
 declare("PARQUET_TPU_PALLAS", "str", "",
-        "mosaic kernel routing: 1=pallas, 0=jnp fallback, off=disable "
-        "the kernel entirely; unset = backend default")
+        "dense bit-unpack routing: 1/pallas = the Pallas kernel (interpret "
+        "mode off the TPU), 0/jnp = the jnp twin, off = per-value gathers; "
+        "unset = the kernel on a TPU, the twin elsewhere")
 declare("PARQUET_TPU_PLAIN_RUNS", "str", "",
         "pin PLAIN fixed-width chunk decode: host|device; unset routes "
         "per backend")

@@ -301,7 +301,7 @@ def concat_columns(parts: List[Column]) -> Column:
             p.materialize_host()
     first = parts[0]
     if first.offsets is not None:
-        values = np.concatenate([np.asarray(p.values) for p in parts])
+        values = _concat_values(parts)
         offs_parts = []
         base = 0
         for p in parts:
@@ -317,7 +317,7 @@ def concat_columns(parts: List[Column]) -> Column:
         if base <= _OFFSET32_LIMIT:
             offsets = offsets.astype(np.int32)
     else:
-        values = np.concatenate([np.asarray(p.values) for p in parts])
+        values = _concat_values(parts)
         offsets = None
     validity, list_offsets, list_validity, def_levels, rep_levels = \
         _concat_structure(parts)
@@ -326,6 +326,35 @@ def concat_columns(parts: List[Column]) -> Column:
                   list_validity=list_validity,
                   num_slots=sum(p.num_slots for p in parts),
                   def_levels=def_levels, rep_levels=rep_levels)
+
+
+def _concat_values(parts: List[Column]):
+    """Values stay where they were decoded: device-resident parts
+    concatenate on the device (a round trip through the host here would
+    hand jit consumers host arrays), anything else on the host."""
+    import jax
+
+    vals = [p.values for p in parts]
+    if all(isinstance(v, jax.Array) for v in vals):
+        import jax.numpy as jnp
+
+        return jnp.concatenate(_on_one_device(vals))
+    return np.concatenate([np.asarray(v) for v in vals])
+
+
+def _on_one_device(tree):
+    """Device arrays of one column can sit on different devices: a mesh
+    dataset read decodes file i on device i % n. A jnp concat refuses
+    that, so move every array onto the first one's (lowest) device; arrays
+    that already share one device set are returned as they are."""
+    import jax
+
+    arrs = [a for a in jax.tree_util.tree_leaves(tree)
+            if isinstance(a, jax.Array)]
+    if len({frozenset(a.devices()) for a in arrs}) <= 1:
+        return tree
+    target = min(arrs[0].devices(), key=lambda d: d.id)
+    return jax.device_put(tree, target)
 
 
 def _concat_structure(parts: List[Column]):
@@ -376,8 +405,8 @@ def _concat_dict_parts(parts: List[Column]) -> Optional[Column]:
         # device-resident chunks: rebase with jnp ops, nothing leaves HBM
         from ..parallel.host_scan import _concat_dictionaries
 
-        dictionary, indices = _concat_dictionaries(
-            [(p.dictionary, p.dict_indices) for p in parts])
+        dictionary, indices = _concat_dictionaries(_on_one_device(
+            [(p.dictionary, p.dict_indices) for p in parts]))
         dict_host = None
     elif all(p.dictionary_host is not None for p in parts):
         idx_parts, base = [], 0

@@ -1,14 +1,20 @@
-"""Native host shim loader: compiles native.cpp → _native.so on first use.
+"""Native host shim loader: compiles native.cpp → _native_<key>.so on first use.
 
 Reference parity: stands in for the reference's amd64 assembly + unsafe Go
 host kernels (SURVEY.md §2.3).  Pure C ABI over ctypes (no pybind11 in this
-image).  Falls back silently to the numpy oracles when a compiler is missing
-— the exact ``purego`` build-tag pattern of the reference.
+image).  Falls back to the numpy oracles when a compiler is missing — the
+``purego`` build-tag pattern of the reference; :data:`build_error` says why,
+and ``chip_smoke.py`` treats a missing shim as an error.
+
+The library's file name carries a hash of the source, the compiler flags
+and the host CPU's feature flags (``-march=native``), so a stale or foreign
+build is never loaded: any change to one of them builds anew.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -19,12 +25,15 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cpp")
-_SO = os.path.join(_HERE, "_native.so")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+          "-pthread")
 from ..utils.locks import make_lock
 
 _lock = make_lock("native.build")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+#: why the shim is unavailable (None while it loads, or before first use)
+build_error: Optional[str] = None
 
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 _u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
@@ -35,18 +44,41 @@ _i64p_w = np.ctypeslib.ndpointer(np.int64, flags=("C_CONTIGUOUS", "WRITEABLE"))
 _i32p_w = np.ctypeslib.ndpointer(np.int32, flags=("C_CONTIGUOUS", "WRITEABLE"))
 
 
-def _build() -> bool:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return True
+def _cpu_flags() -> bytes:
     try:
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-             "-pthread", _SRC, "-o", _SO + ".tmp"],
-            check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)
-        return True
-    except Exception:
-        return False
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith(b"flags"):
+                    return line
+    except OSError:
+        pass
+    return b""
+
+
+def _so_path() -> str:
+    """Where the build of the present source, flags and CPU lives."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_flags())
+    return os.path.join(_HERE, f"_native_{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> Optional[str]:
+    """Build ``so`` unless it exists; returns the error, or None."""
+    if os.path.exists(so):
+        return None
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp],
+                       check=True, capture_output=True, timeout=120)
+    except subprocess.CalledProcessError as e:
+        return f"g++ failed: {e.stderr.decode(errors='replace')[-2000:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"g++ could not run: {e}"
+    os.replace(tmp, so)
+    return None
 
 
 def _auto_threads() -> int:
@@ -60,7 +92,7 @@ def _auto_threads() -> int:
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, build_error
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -70,12 +102,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
         from ..utils.env import env_bool
 
         if env_bool("PARQUET_TPU_NO_NATIVE"):
+            build_error = "disabled by PARQUET_TPU_NO_NATIVE"
             return None
-        if not _build():
+        so = _so_path()
+        build_error = _build(so)
+        if build_error is not None:
             return None
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            build_error = f"cannot load {so}: {e}"
             return None
         lib.pq_plain_byte_array.restype = ctypes.c_int64
         lib.pq_plain_byte_array.argtypes = [

@@ -15,14 +15,15 @@ registry.  Design per SURVEY.md §7:
   so XLA fuses freely.  Pallas variants for the hottest kernels live in
   ``pallas_kernels.py``.
 
-**32-bit-lane discipline (TPU-first):** TPU VPUs are 32-bit-lane machines and
-this stack's TPU compile path rewrites away 64-bit element types (64-bit
-``bitcast_convert_type`` is unimplemented there, and miscompiles on some CPU
-builds).  So device kernels NEVER bitcast 64-bit types: 64-bit columns live on
-device as ``(n, 2)`` uint32 pairs — byte-exact, converted to int64/float64 by
-a zero-copy ``.view()`` at host materialization — and all bit-unpacking is
-32-bit shift/mask arithmetic.  Only DELTA_BINARY_PACKED's int64 prefix-sum
-uses (emulated) s64 *arithmetic*, which the rewrite does support.
+**32-bit-lane discipline (TPU-first):** TPU VPUs are 32-bit-lane machines;
+the TPU compiler emulates 64-bit element types, and its float64 is not
+bit-exact (a float64 bitcast on a v5e and read back differed from the file's
+bytes — PR 21 chip run).  So device kernels NEVER bitcast to 64-bit types:
+64-bit columns live on device as ``(n, 2)`` uint32 pairs — byte-exact,
+converted to int64/float64 by a zero-copy ``.view()`` at host
+materialization — and all bit-unpacking is 32-bit shift/mask arithmetic.
+Only DELTA_BINARY_PACKED's int64 prefix-sum uses (emulated) s64
+*arithmetic*, which is exact.
 
 int64 note: importing this module enables jax x64 (needed for s64 cumsum and
 wide bit offsets) unless PARQUET_TPU_NO_X64 is set.
@@ -69,9 +70,25 @@ def _as_words(buf: jax.Array) -> jax.Array:
     """uint8 staged buffer → uint32 little-endian word view (zero-padded to a
     word boundary; out-of-range word gathers are clamped by XLA and the
     garbage bits always fall outside the value mask)."""
-    if buf.shape[0] % 4:
-        buf = jnp.pad(buf, (0, 4 - buf.shape[0] % 4))
-    return jax.lax.bitcast_convert_type(buf.reshape(-1, 4), _U32)
+    return bitcast_rows(buf, 4, _U32)
+
+
+#: row length of :func:`cumsum`'s blocked scan
+_SCAN_BLOCK = 1024
+
+
+def cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D array (``jnp.cumsum`` semantics and
+    dtype), blocked: the TPU compiler takes ~20 s over a flat cumsum of 1M
+    values and under a second over rows of 1,024 plus a cumsum of the row
+    totals (recursively, for very long inputs)."""
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    rows = jnp.pad(x, (0, -n % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
+    inner = jnp.cumsum(rows, axis=1)
+    tot = inner[:, -1]
+    return (inner + (cumsum(tot) - tot)[:, None]).reshape(-1)[:n]
 
 
 def _word_at(bit_starts: jax.Array):
@@ -86,18 +103,30 @@ def _word_at(bit_starts: jax.Array):
 # ---------------------------------------------------------------------------
 
 
+def bitcast_rows(buf: jax.Array, row_bytes: int, dtype) -> jax.Array:
+    """The whole uint8 staged buffer as rows of ``row_bytes // size(dtype)``
+    elements of ``dtype``, zero-padded to whole rows.  Callers slice the
+    rows they need AFTER the bitcast: the TPU compiler takes minutes over a
+    reshape+bitcast of a buffer sliced to an arbitrary length (1M 8-byte
+    values: 319 s) and a second over the power-of-two staging bucket."""
+    if buf.shape[0] % row_bytes:
+        buf = jnp.pad(buf, (0, row_bytes - buf.shape[0] % row_bytes))
+    k = row_bytes // jnp.dtype(dtype).itemsize
+    rows = buf.reshape(-1, k, row_bytes // k) if k > 1 else \
+        buf.reshape(-1, row_bytes)
+    return jax.lax.bitcast_convert_type(rows, dtype)
+
+
 @partial(jax.jit, static_argnames=("n", "dtype"))
 def bitcast_fixed32(buf: jax.Array, n: int, dtype: str) -> jax.Array:
     """uint8 → {int32,uint32,float32}[n] (PLAIN 4-byte types)."""
-    return jax.lax.bitcast_convert_type(
-        buf[: n * 4].reshape(n, 4), jnp.dtype(dtype)).reshape(n)
+    return bitcast_rows(buf, 4, jnp.dtype(dtype))[:n]
 
 
 @partial(jax.jit, static_argnames=("n",))
 def fixed64_pairs(buf: jax.Array, n: int) -> jax.Array:
     """uint8 → uint32[n,2] lo/hi pairs (PLAIN 8-byte types, byte-exact)."""
-    return jax.lax.bitcast_convert_type(
-        buf[: n * 8].reshape(n, 2, 4), _U32).reshape(n, 2)
+    return bitcast_rows(buf, 8, _U32)[:n]
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -221,7 +250,7 @@ def delta_decode32(
     deltas = raw + min32[mb]
     first32 = (first_value.astype(jnp.int64) & jnp.int64(0xFFFFFFFF)).astype(_U32)
     seq = jnp.concatenate([first32.reshape(1), deltas])
-    return jax.lax.bitcast_convert_type(jnp.cumsum(seq), jnp.int32)
+    return jax.lax.bitcast_convert_type(cumsum(seq), jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("n", "vpm"))
@@ -245,7 +274,7 @@ def delta_decode64(
     raw = lo.astype(jnp.int64) | (hi.astype(jnp.int64) << 32)
     deltas = raw + mb_min_deltas[mb]
     seq = jnp.concatenate([first_value.astype(jnp.int64).reshape(1), deltas])
-    return _i64_to_pairs(jnp.cumsum(seq))
+    return _i64_to_pairs(cumsum(seq))
 
 
 def _i64_to_pairs(v: jax.Array) -> jax.Array:
@@ -402,13 +431,13 @@ def validity_from_def(def_levels: jax.Array, max_def: int) -> jax.Array:
 @jax.jit
 def cumsum_offsets(lengths: jax.Array) -> jax.Array:
     return jnp.concatenate([jnp.zeros(1, jnp.int64),
-                            jnp.cumsum(lengths.astype(jnp.int64))])
+                            cumsum(lengths.astype(jnp.int64))])
 
 
 @jax.jit
 def scatter_valid(values: jax.Array, validity: jax.Array) -> jax.Array:
     """Dense present values → slot-aligned array (nulls get 0)."""
-    slot_of_value = jnp.cumsum(validity.astype(jnp.int32)) - 1
+    slot_of_value = cumsum(validity.astype(jnp.int32)) - 1
     gathered = values[jnp.clip(slot_of_value, 0, values.shape[0] - 1)]
     zero = jnp.zeros((), dtype=values.dtype)
     if values.ndim > 1:
@@ -490,7 +519,7 @@ def _asl_cums(d: jax.Array, r: jax.Array, dk: int):
 def _asl_finish(d, r, n_rows: int, n_elem: int, dk: int, max_def: int):
     inst_mask = r == 0
     elem = d >= dk
-    cum = jnp.cumsum(elem.astype(jnp.int32))
+    cum = cumsum(elem.astype(jnp.int32))
     inst_idx = jnp.nonzero(inst_mask, size=n_rows, fill_value=0)[0].astype(jnp.int32)
     starts = cum[inst_idx] - elem[inst_idx].astype(jnp.int32)
     offsets = jnp.concatenate(
@@ -550,7 +579,7 @@ def _an_finish(d, r, sizes, reps, defs, max_def: int):
             elem = (r < reps[i + 1]) & (d >= dk)
         else:
             elem = d >= dk
-        cum = jnp.cumsum(elem.astype(jnp.int32))
+        cum = cumsum(elem.astype(jnp.int32))
         starts = (jnp.where(inst_idx > 0, cum[jnp.maximum(inst_idx - 1, 0)], 0)
                   if not empty else jnp.zeros(0, jnp.int32))
         total = cum[-1:] if not empty else jnp.zeros(1, jnp.int32)

@@ -3,7 +3,9 @@
 Reference parity: the role of ``internal/bitpack/unpack_int32_amd64.s`` etc.
 (SURVEY.md §2.3) — hand-tuned kernels under the same interfaces as the
 portable path.  Tested in interpret mode against the numpy oracle (the
-purego-equivalence pattern) and jit-compiled on the real chip by the bench.
+purego-equivalence pattern), compiled for a described v5e by
+``tests/test_tpu_compile.py`` with the package's own settings (x64 on), and
+run on the chip by ``chip_smoke.py``.
 
 Design note (TPU-first): data-dependent gathers are the enemy on a TPU VPU —
 so the flagship kernel is a *gather-free* bit-unpack.  For a static width
@@ -14,24 +16,17 @@ a (block, w)-word tile in VMEM.  The generic mixed-width path stays in
 ops/device.py (XLA gathers); chunks whose streams are single-width (dict
 indexes, most delta miniblocks after host bucketing) route here.
 
-Measured on the real v5e (round 2, 8M values): ``unpack_bits_dense`` beats
-the jnp twin 2-4x (w=1: 73ms vs 283ms; w=8: 67ms vs 167ms; w=16: 67ms vs
-145ms), so it is the default TPU route for w ≤ 16 (device_reader._use_pallas).
-KNOWN MOSAIC BUG: for w ≥ 17 the compiled shift-formulation kernel
-deterministically corrupts the word-straddling columns whose shift is 16
-(sparse wrong values; the jnp twin is correct at every width).  Minimized
-standalone repro: ``scripts/mosaic_repro.py``; on-chip confirmation
-2026-07-31 (``MOSAIC_REPRO_ONCHIP.json``): shift FAILS at w=17/20/24/31,
-always and only at the shift-16 lanes.  The bad pattern is ``(lo >> 16) |
-(hi << 16)``; :func:`unpack_bits_dense` reformulates the straddle as a
-MULTIPLY (``hi * 2**(32-sh)``) for w ≥ 17 — semantically identical, and
-the same trial proved it EXACT on-chip at w ∈ {16, 17, 20, 24, 31} (plus
-w = 27 in an 8M-value production-kernel run), so the router now takes the
-Pallas kernel at all widths on TPU (device_reader._use_pallas).
-Upstream report: the complete ready-to-file issue text is
-``UPSTREAM_ISSUE_mosaic.md`` at the repo root (zero-egress environment —
-paste into the JAX tracker with scripts/mosaic_repro.py +
-MOSAIC_REPRO_ONCHIP.json attached).
+x64: the package runs with ``jax_enable_x64`` on, so index maps return
+int32 constants (a bare ``0`` traces as i64, which Mosaic cannot return)
+and no kernel reduces with a float op (a boolean ``all`` lowers to an f64
+min).  Until PR 21 none of these kernels compiled under x64 on JAX 0.9.
+
+KNOWN MOSAIC BUG (round 2): for w ≥ 17 the shift-formulation kernel
+corrupted the word-straddling columns whose shift is 16 on a v5e.  Minimized
+standalone repro: ``scripts/mosaic_repro.py`` (``MOSAIC_REPRO_ONCHIP.json``;
+upstream text ``UPSTREAM_ISSUE_mosaic.md``).  :func:`unpack_bits_dense`
+reformulates the straddle as a MULTIPLY (``hi * 2**(32-sh)``) for w ≥ 17,
+which the same trial found exact.
 """
 
 from __future__ import annotations
@@ -47,6 +42,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _MASK32 = 0xFFFFFFFF
+
+
+def _row_block(i):
+    """Index map of a grid over row blocks.  The constants are int32: the
+    package runs with ``jax_enable_x64`` on, where a bare ``0`` traces as
+    an i64 that Mosaic cannot return next to the i32 grid index."""
+    return i, jnp.int32(0)
 
 
 def _unpack_block_kernel(words_ref, out_ref, *, w: int, straddle: str):
@@ -102,9 +104,9 @@ def unpack_bits_dense(packed_words: jax.Array, n: int, w: int,
         functools.partial(_unpack_block_kernel, w=w, straddle=straddle),
         out_shape=jax.ShapeDtypeStruct((gpad, 32), jnp.uint32),
         grid=(gpad // block,),
-        in_specs=[pl.BlockSpec((block, w), lambda i: (i, 0),
+        in_specs=[pl.BlockSpec((block, w), _row_block,
                                memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((block, 32), lambda i: (i, 0),
+        out_specs=pl.BlockSpec((block, 32), _row_block,
                                memory_space=pltpu.VMEM),
         interpret=interpret,
     )(words2d)
@@ -135,62 +137,6 @@ def unpack_bits_dense_jnp(packed_words: jax.Array, n: int, w: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Fused dictionary expand+gather for single-width bit-packed index streams
-# ---------------------------------------------------------------------------
-
-
-def _dict_unpack_gather_kernel(words_ref, dict_ref, out_ref, *, w: int):
-    """Unpack 32-bit-group indexes and gather from a VMEM-resident dictionary
-    via one-hot matmul (MXU-friendly for small dictionaries)."""
-    words = words_ref[:]
-    mask = jnp.uint32((1 << w) - 1 if w < 32 else _MASK32)
-    cols = []
-    for j in range(32):
-        bitpos = j * w
-        k = bitpos >> 5
-        sh = bitpos & 31
-        val = words[:, k] >> jnp.uint32(sh)
-        if sh + w > 32:
-            val = val | (words[:, k + 1] << jnp.uint32(32 - sh))
-        cols.append((val & mask).reshape(-1, 1))
-    idx = jnp.concatenate(cols, axis=1).astype(jnp.int32)  # (B, 32)
-    d = dict_ref[:]  # (D,) values in VMEM
-    flat = idx.reshape(-1)
-    onehot = (flat[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (flat.shape[0], d.shape[0]), 1))
-    vals = jnp.sum(jnp.where(onehot, d[None, :], 0), axis=1)
-    out_ref[:] = vals.reshape(idx.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "w", "block", "interpret"))
-def dict_unpack_gather(packed_words: jax.Array, dictionary: jax.Array, n: int,
-                       w: int, block: int = 128, interpret: bool = False
-                       ) -> jax.Array:
-    """Fused: bit-unpack dictionary indexes + gather values, one VMEM pass
-    (no HBM round-trip for the index stream).  For small dictionaries."""
-    groups = (n + 31) // 32
-    gpad = (groups + block - 1) // block * block
-    need_words = gpad * max(w, 1)
-    if packed_words.shape[0] < need_words:
-        packed_words = jnp.pad(packed_words, (0, need_words - packed_words.shape[0]))
-    words2d = packed_words[: gpad * w].reshape(gpad, w)
-    out = pl.pallas_call(
-        functools.partial(_dict_unpack_gather_kernel, w=w),
-        out_shape=jax.ShapeDtypeStruct((gpad, 32), dictionary.dtype),
-        grid=(gpad // block,),
-        in_specs=[
-            pl.BlockSpec((block, w), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((dictionary.shape[0],), lambda i: (0,),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block, 32), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )(words2d, dictionary)
-    return out.reshape(-1)[:n]
-
-
-# ---------------------------------------------------------------------------
 # SBBF bloom block math (vector twin of bloom.py; probes a batch of hashes
 # against gathered blocks — the gather happens outside, the 8-salt block math
 # is the vector part, matching the reference's AVX2 block kernel split)
@@ -202,28 +148,50 @@ _SALT = np.array([
 ], dtype=np.uint32)
 
 
-def _bloom_check_kernel(blocks_ref, low_ref, salts_ref, out_ref):
-    """blocks: (B, 8) gathered filter blocks; low: (B, 1) low-32 hash bits."""
-    low = low_ref[:][:, 0]
-    salts = salts_ref[:][0]
-    bit = (low[:, None] * salts[None, :]) >> jnp.uint32(27)
-    masks = jnp.uint32(1) << (bit & jnp.uint32(31))
-    hit = (blocks_ref[:] & masks) == masks
-    out_ref[:] = jnp.all(hit, axis=1, keepdims=True)
+#: probes per grid step of :func:`bloom_check_blocks` (lane axis): VMEM
+#: holds (8 + 1 + 1) x 2048 words per step whatever the batch size
+BLOOM_BLOCK = 2048
+
+
+def _bloom_check_kernel(blocks_ref, low_ref, out_ref):
+    """blocks: (8, B) gathered filter blocks, one salt lane per row; low:
+    (1, B) low-32 hash bits.  The all-salts test is an unrolled AND of
+    integer compares — no reduction (a boolean ``all`` lowers to a float
+    min that Mosaic refuses under x64)."""
+    low = low_ref[0, :]
+    ok = None
+    for i, salt in enumerate(_SALT):
+        bit = (low * jnp.uint32(int(salt))) >> jnp.uint32(27)
+        mask = jnp.uint32(1) << bit
+        hit = (blocks_ref[i, :] & mask) == mask
+        ok = hit if ok is None else ok & hit
+    out_ref[0, :] = ok.astype(jnp.int32)
+
+
+def _lane_block(i):
+    return jnp.int32(0), i
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bloom_check_blocks(blocks: jax.Array, low_bits: jax.Array,
                        interpret: bool = False) -> jax.Array:
-    """Check pre-gathered SBBF blocks against hash low bits (vector part of
-    the probe; block gather by high bits happens in XLA)."""
+    """Check pre-gathered SBBF blocks ``(n, 8)`` against hash low bits
+    ``(n,)`` (vector part of the probe; block gather by high bits happens
+    in XLA).  A grid over probe blocks bounds VMEM for any ``n``."""
     n = blocks.shape[0]
-    return pl.pallas_call(
+    npad = -(-max(n, 1) // BLOOM_BLOCK) * BLOOM_BLOCK
+    lanes = jnp.pad(blocks.astype(jnp.uint32).T, ((0, 0), (0, npad - n)))
+    low = jnp.pad(low_bits.astype(jnp.uint32), (0, npad - n)).reshape(1, -1)
+    out = pl.pallas_call(
         _bloom_check_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.bool_),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.int32),
+        grid=(npad // BLOOM_BLOCK,),
+        in_specs=[pl.BlockSpec((8, BLOOM_BLOCK), _lane_block,
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, BLOOM_BLOCK), _lane_block,
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, BLOOM_BLOCK), _lane_block,
+                               memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(blocks, low_bits.reshape(-1, 1), jnp.asarray(_SALT).reshape(1, 8)).reshape(-1)
+    )(lanes, low)
+    return out[0, :n] != 0

@@ -1053,6 +1053,8 @@ def _make_fused_span(path, out_cols, per_col, lo, hi, probe, n_rows):
     import jax
     import jax.numpy as jnp
 
+    from ..ops import device as dev
+
     key_chunk, key_dplan, _, key_trim = per_col[path]
     key_no_nulls = key_dplan.total_values == key_dplan.total_slots
     infos = [(c, per_col[c][0], per_col[c][1], per_col[c][3]) for c in out_cols]
@@ -1061,7 +1063,7 @@ def _make_fused_span(path, out_cols, per_col, lo, hi, probe, n_rows):
         kcol = _FlatForm(*key_form)
         mask = _key_mask_device(key_chunk.leaf, kcol, lo, hi, key_trim,
                                 n_rows, key_no_nulls, values=probe)
-        pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+        pos = dev.cumsum(mask.astype(jnp.int32)) - 1
         tgt = jnp.where(mask, pos, n_rows)
         outs = {}
         vouts = {}
@@ -1119,6 +1121,7 @@ def _scan_dispatch(state, carrier: _ScanCarrier,
     import jax.numpy as jnp
 
     from ..format.enums import Type
+    from ..ops import device as dev
     from . import device_reader as dr
 
     path, out_cols = state["path"], state["out_cols"]
@@ -1149,7 +1152,7 @@ def _scan_dispatch(state, carrier: _ScanCarrier,
             no_nulls = dplan.total_values == dplan.total_slots
             mask = _key_mask_device(chunk.leaf, key, lo, hi, trim, n_rows,
                                     no_nulls, values=probe)
-            pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+            pos = dev.cumsum(mask.astype(jnp.int32)) - 1
             tgt = jnp.where(mask, pos, n_rows)  # survivors -> prefix
             cnt = jnp.sum(mask.astype(jnp.int32))
             ragged_idx = (_compact(jnp.arange(n_rows, dtype=jnp.int32), tgt)

@@ -217,6 +217,12 @@ class ShardedTable:
         return jax.device_put(host, sharding)
 
 
+#: prep verdict of :func:`read_table_sharded` for a fully PLAIN string
+#: chunk: it ships as the host-assembled ragged pair by design, so it is
+#: counted as ``chunks_host_ragged``, not as a fallback
+_RAGGED = object()
+
+
 def _decode_prepped(reader, prep_out):
     """Device-decode a prepared chunk, or fall back to host decode when the
     prescan/decode hit an unsupported shape (mixed page encodings, missing
@@ -226,7 +232,9 @@ def _decode_prepped(reader, prep_out):
     from ..io.reader import decode_chunk_host
     from .device_reader import _Unsupported, decode_staged
 
-    if prep_out is not None:
+    if prep_out is _RAGGED:
+        counters.inc("chunks_host_ragged")
+    elif prep_out is not None:
         plan, staged = prep_out
         try:
             col = decode_staged(reader.leaf, Type(reader.meta.type), plan,
@@ -234,8 +242,9 @@ def _decode_prepped(reader, prep_out):
             counters.inc("chunks_device_decoded")
             return col, plan.total_slots - plan.total_values
         except _Unsupported:
-            pass
-    counters.inc("chunks_host_fallback")
+            counters.inc("chunks_host_fallback")
+    else:
+        counters.inc("chunks_host_fallback")
     col = decode_chunk_host(reader)
     n_nulls = 0
     if col.validity is not None:
@@ -350,7 +359,7 @@ def read_table_sharded(source, mesh: Optional[Mesh] = None,
                 # fully PLAIN chunk: it ships as the host-assembled ragged
                 # pair anyway — device-staging it first would be a wasted
                 # H2D+D2H round trip
-                return None, reader
+                return _RAGGED, reader
         try:
             return prepare_chunk(reader, device=devs[rg % len(devs)]), reader
         except _Unsupported:
@@ -570,8 +579,6 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
     distributed scan wants).  This is the full per-step compute of the decode
     "model" under real dp sharding.
     """
-    from jax.experimental.shard_map import shard_map
-
     spec = P(axis)
     rep = P()
 
@@ -589,11 +596,11 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
         nrows = jax.lax.psum(jnp.sum(validity.astype(jnp.int32)), axis)
         return lo[None], hi[None], validity[None], nrows
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(spec,) * 7,
         out_specs=(spec, spec, spec, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

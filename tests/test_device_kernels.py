@@ -309,3 +309,20 @@ def test_assemble_nested_depth3(rng):
     if want.validity is not None:
         np.testing.assert_array_equal(np.asarray(got_leaf),
                                       np.asarray(want.validity))
+
+
+@pytest.mark.parametrize("n", [0, 1, 1024, 1025, 70_001])
+@pytest.mark.parametrize("dtype", [np.int32, np.uint32, np.int64, np.bool_])
+def test_blocked_cumsum_matches_jnp(n, dtype, rng):
+    """The blocked prefix sum (fast to compile for the TPU) has
+    ``jnp.cumsum``'s values and dtype, wrap-around included."""
+    import jax.numpy as jnp
+
+    from parquet_tpu.ops import device as dev
+
+    x = (rng.random(n) < 0.5 if dtype is np.bool_
+         else rng.integers(0, 2**31, n).astype(dtype))
+    got = dev.cumsum(jnp.asarray(x))
+    want = jnp.cumsum(jnp.asarray(x))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -211,8 +211,9 @@ def test_dense_dict_route_modes(mode, monkeypatch, rng):
     _check(raw, t)
 
 
-def test_dense_dict_fused_small_dictionary(monkeypatch, rng):
-    """Pallas fused unpack+gather engages for small fixed-width dicts."""
+def test_dense_dict_small_dictionary_pallas(monkeypatch, rng):
+    """Forced Pallas on a small fixed-width dictionary: the dense unpack
+    kernel then the XLA gather (indices materialized; no fused kernel)."""
     from parquet_tpu.parallel import device_reader as dr
 
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "1")
@@ -225,7 +226,7 @@ def test_dense_dict_fused_small_dictionary(monkeypatch, rng):
     pf = ParquetFile(raw)
     chunk = pf.row_group(0).column(0)
     col = dr.decode_chunk_device(chunk, fallback=False)
-    assert col.dict_indices is None and col.values is not None  # fused
+    assert col.dict_indices is not None and col.values is not None
     np.testing.assert_array_equal(np.asarray(col.values),
                                   t.column("v").to_numpy())
 
@@ -289,24 +290,25 @@ def test_device_all_null_chunks(typ_kw):
     assert len(arr) == 1500 and arr.null_count == 1500
 
 
-def test_use_pallas_gate_wide_widths(monkeypatch):
-    """Wide widths are no longer jnp-pinned: the multiply-straddle
-    formulation passed its on-chip trial (MOSAIC_REPRO_ONCHIP.json — shift
-    corrupts w >= 17, mul exact at every width), so forced Pallas admits
-    every width and 'auto' routes on backend alone."""
+@pytest.mark.parametrize("mode,w,want", [
+    ("1", 8, True), ("1", 16, True), ("1", 17, True), ("1", 20, True),
+    ("1", 24, True), ("1", 31, True), ("1", 32, True),
+    ("0", 8, False), ("0", 20, False),
+    ("", 8, "tpu"), ("", 20, "tpu"),
+])
+def test_use_pallas_gate_wide_widths(mode, w, want, monkeypatch):
+    """Forced Pallas admits every width, forced jnp none, and 'auto'
+    routes on the backend alone (the kernel on a TPU, the jnp twin
+    elsewhere) — with no process-wide 'broken' latch in between."""
+    import jax
+
     from parquet_tpu.parallel import device_reader as dr
 
-    monkeypatch.setattr(dr, "_pallas_broken", False)
-    monkeypatch.setenv("PARQUET_TPU_PALLAS", "1")
-    for w in (8, 16, 17, 20, 24, 31, 32):
-        assert dr._use_pallas(w), w
-    monkeypatch.setenv("PARQUET_TPU_PALLAS", "0")
-    assert not dr._use_pallas(8)
-    assert not dr._use_pallas(20)
-    monkeypatch.setenv("PARQUET_TPU_PALLAS", "")
-    # auto: CPU backend in tests -> jnp twin at every width
-    assert not dr._use_pallas(8)
-    assert not dr._use_pallas(20)
+    monkeypatch.setenv("PARQUET_TPU_PALLAS", mode)
+    if want == "tpu":
+        want = jax.default_backend() == "tpu"
+    assert dr._use_pallas(w) is want
+    assert not hasattr(dr, "_pallas_broken")
 
 
 def test_byte_stream_split_flba_float16_device(rng):

@@ -31,13 +31,34 @@ def test_unpack_bits_dense_jnp_twin(w, rng):
     np.testing.assert_array_equal(np.asarray(out), v.astype(np.uint32))
 
 
-def test_dict_unpack_gather(rng):
-    w = 5
-    d = rng.random(32, dtype=np.float32)
-    idx = rng.integers(0, 32, size=1000, dtype=np.uint64)
-    words = _pack_words(idx, w)
-    out = pk.dict_unpack_gather(words, d, 1000, w, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), d[idx])
+@pytest.mark.parametrize("dtype", [np.int32, np.float32, np.int64])
+def test_dense_dict_unfused_route(dtype, monkeypatch, rng):
+    """Forced Pallas on a small fixed-width dictionary: the dense unpack
+    kernel (interpret mode off the TPU) then the XLA gather — the one route
+    now that the fused unpack+gather kernel is gone (it never compiled for
+    the chip)."""
+    import io
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from parquet_tpu.io.reader import ParquetFile
+    from parquet_tpu.parallel import device_reader as dr
+
+    monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
+    monkeypatch.setenv("PARQUET_TPU_DICT_RUNS", "device")
+    d = (rng.random(32) * 1000).astype(dtype)
+    v = d[rng.integers(0, 32, 5000)]
+    buf = io.BytesIO()
+    pq.write_table(pa.table({"v": v}), buf, use_dictionary=True,
+                   data_page_size=1 << 12)
+    chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
+    col = dr.decode_chunk_device(chunk, fallback=False)
+    assert col.dict_indices is not None  # indices materialized: unfused
+    got = np.asarray(col.values)
+    if dtype == np.int64:
+        got = got.view(np.int64).reshape(-1)
+    np.testing.assert_array_equal(got, v)
 
 
 def test_bloom_check_blocks(rng):
@@ -72,19 +93,89 @@ def test_unpack_wide_straddle_variants(w, straddle, rng):
     np.testing.assert_array_equal(np.asarray(out), v.astype(np.uint32))
 
 
-def test_wide_width_routing(monkeypatch):
-    """Wide widths route like narrow ones now that the multiply-straddle
-    passed its on-chip trial; 'mul' remains accepted and equals 'auto'."""
+@pytest.mark.parametrize("mode,w,on_tpu_only", [
+    ("", 20, True), ("", 8, True), ("auto", 31, True),
+    ("pallas", 20, False), ("pallas", 8, False), ("1", 17, False),
+])
+def test_wide_width_routing(mode, w, on_tpu_only, monkeypatch):
+    """Wide widths route like narrow ones: 'auto' takes the kernel on a
+    TPU backend only, a forced 'pallas' everywhere (interpret mode off
+    the TPU)."""
     from parquet_tpu.parallel import device_reader as dr
     import jax
 
-    on_tpu = jax.default_backend() == "tpu"
-    monkeypatch.setattr(dr, "_pallas_broken", False)
-    monkeypatch.delenv("PARQUET_TPU_PALLAS", raising=False)
-    assert dr._use_pallas(20) is on_tpu
+    monkeypatch.setenv("PARQUET_TPU_PALLAS", mode)
+    want = jax.default_backend() == "tpu" if on_tpu_only else True
+    assert dr._use_pallas(w) is want
+
+
+class _KernelBroke(RuntimeError):
+    pass
+
+
+def _broken(*a, **k):
+    raise _KernelBroke("injected kernel failure")
+
+
+def test_injected_unpack_failure_raises_dense_dict(monkeypatch, rng):
+    """A Pallas unpack failure on the dense dictionary route raises: it is
+    not swapped for the jnp twin behind the caller's back."""
+    import io
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from parquet_tpu.io.reader import ParquetFile
+    from parquet_tpu.parallel import device_reader as dr
+
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
-    assert dr._use_pallas(20) is True
-    assert dr._use_pallas(8) is True
-    monkeypatch.setenv("PARQUET_TPU_PALLAS", "mul")
-    assert dr._use_pallas(20) is on_tpu  # compat alias for 'auto'
-    assert dr._use_pallas(8) is on_tpu
+    monkeypatch.setenv("PARQUET_TPU_DICT_RUNS", "device")
+    monkeypatch.setattr(pk, "unpack_bits_dense", _broken)
+    dr._dense_unpack_pages.clear_cache()
+    buf = io.BytesIO()
+    pq.write_table(pa.table({"v": rng.integers(0, 900, 5000)}), buf,
+                   use_dictionary=True)
+    chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
+    with pytest.raises(_KernelBroke):
+        dr.decode_chunk_device(chunk)
+    dr._dense_unpack_pages.clear_cache()
+
+
+def test_injected_unpack_failure_raises_delta(monkeypatch, rng):
+    """The same on the dense DELTA_BINARY_PACKED route."""
+    import io
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from parquet_tpu.io.reader import ParquetFile
+    from parquet_tpu.parallel import device_reader as dr
+
+    monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
+    monkeypatch.setenv("PARQUET_TPU_DELTA_RUNS", "device")
+    monkeypatch.setattr(pk, "unpack_bits_dense", _broken)
+    dr._delta_decode_dense.clear_cache()
+    buf = io.BytesIO()
+    pq.write_table(pa.table({"v": np.cumsum(rng.integers(0, 1000, 5000))}),
+                   buf, use_dictionary=False,
+                   column_encoding={"v": "DELTA_BINARY_PACKED"})
+    chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
+    with pytest.raises(_KernelBroke):
+        dr.decode_chunk_device(chunk)
+    dr._delta_decode_dense.clear_cache()
+
+
+def test_injected_bloom_kernel_failure_raises(monkeypatch):
+    """On a TPU backend the batched bloom probe runs the Pallas kernel; its
+    failure raises instead of quietly taking the jnp twin."""
+    import jax
+
+    from parquet_tpu.io import bloom
+
+    filt = bloom.SplitBlockFilter.for_ndv(1000, 10)
+    hashes = bloom.xxh64_u64(np.arange(100, dtype=np.uint64))
+    filt.insert_hashes(hashes)
+    monkeypatch.setattr(pk, "bloom_check_blocks", _broken)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(_KernelBroke):
+        filt.check_hashes_device(hashes)

@@ -1,0 +1,156 @@
+"""The main path's Pallas kernels compile for a TPU v5e, with the package
+imported (so ``jax_enable_x64`` is on, as in production).
+
+Ahead-of-time compiles for a *described* v5e (no chip needed): what the
+TPU compiler refuses here — an i64 index map, a float reduction, more VMEM
+than a kernel may use — it would refuse on the chip, where interpret mode
+hides it.  The topology is described in a module fixture, never at import:
+only one process may load libtpu, and under xdist only the worker given
+this file should.  The describe call and every compile run on a worker
+thread with a deadline, so a libtpu hang fails the test instead of running
+the suite into the driver's clock.
+"""
+
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import parquet_tpu  # noqa: F401  (x64 on, as the package runs)
+from parquet_tpu.ops import pallas_kernels as pk
+
+DESCRIBE_S = 120
+# each compile here takes ~2 s; the bound also catches compile-time
+# blow-ups (a PLAIN 8-byte bitcast over a sliced buffer took 319 s)
+COMPILE_S = 60
+N_VALUES = 8 << 20  # 8M values: the real dense-unpack size
+ROWS = 1_000_000  # one lineitem row group
+
+
+def bounded(fn, limit_s, what):
+    box = {}
+
+    def run():
+        try:
+            box["out"] = fn()
+        except BaseException as e:  # re-raised on the test thread
+            box["err"] = e
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    th.join(limit_s)
+    if th.is_alive():
+        pytest.fail(f"{what} still running after {limit_s}s")
+    if "err" in box:
+        raise box["err"]
+    return box["out"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def describe():
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            return e
+
+    got = bounded(describe, DESCRIBE_S, "describing a v5e:2x2 topology")
+    if isinstance(got, Exception):
+        pytest.skip(f"no v5e:2x2 topology can be described here: {got}")
+    yield got
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compile_for(fn, *shapes):
+    """AOT-compile ``fn`` at ``shapes``; returns the compiled HLO text."""
+    return bounded(lambda: jax.jit(fn).lower(*shapes).compile().as_text(),
+                   COMPILE_S, f"compiling {getattr(fn, '__name__', fn)}")
+
+
+def test_package_runs_with_x64():
+    assert jax.config.jax_enable_x64
+
+
+@pytest.mark.parametrize("w", [1, 8, 17, 31])
+def test_unpack_bits_dense_compiles(w, one_chip):
+    words = jax.ShapeDtypeStruct((N_VALUES // 32 * w,), jnp.uint32,
+                                 sharding=one_chip)
+    hlo = compile_for(lambda x: pk.unpack_bits_dense(x, N_VALUES, w), words)
+    assert "tpu_custom_call" in hlo  # the Mosaic kernel, not a fallback
+
+
+@pytest.mark.parametrize("n", [pk.BLOOM_BLOCK, 100_000])
+def test_bloom_check_blocks_compiles(n, one_chip):
+    blocks = jax.ShapeDtypeStruct((n, 8), jnp.uint32, sharding=one_chip)
+    low = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    hlo = compile_for(pk.bloom_check_blocks, blocks, low)
+    assert "tpu_custom_call" in hlo
+
+
+def staged(nbytes, sharding):
+    """The staged uint8 buffer the device reader puts: its power-of-two
+    bucket (``ops.device.pad_to_bucket``)."""
+    from parquet_tpu.ops.device import pad_to_bucket
+
+    n = len(pad_to_bucket(np.zeros(nbytes, np.uint8)))
+    return jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=sharding)
+
+
+@pytest.mark.parametrize("pallas", [True, False])
+def test_dense_unpack_pages_compiles(pallas, one_chip):
+    """The jitted dense dict-index decode step at a lineitem date column's
+    real width: ~4,000 distinct days -> 12-bit indexes, 1M-row row group
+    in two 32-aligned pages."""
+    from parquet_tpu.parallel.device_reader import _dense_unpack_pages
+
+    w = 12
+    total = -(-ROWS // 32) * 32
+    nbytes = total * w // 8
+    pages = ((0, 524_288), (524_288, ROWS - 524_288))
+    hlo = compile_for(
+        lambda b: _dense_unpack_pages(b, nbytes, total, w, pages, pallas,
+                                      False), staged(nbytes, one_chip))
+    assert ("tpu_custom_call" in hlo) is pallas
+
+
+@pytest.mark.parametrize("width", [4, 8])
+def test_plain_fixed_width_compiles(width, one_chip):
+    """PLAIN int32/int64/double chunk decode at a row group's 1M values."""
+    from parquet_tpu.ops import device as dev
+
+    buf = staged(ROWS * width, one_chip)
+    if width == 8:
+        compile_for(lambda b: dev.fixed64_pairs(b, ROWS), buf)
+    else:
+        compile_for(lambda b: dev.bitcast_fixed32(b, ROWS, "int32"), buf)
+
+
+def test_prefix_sum_compiles_fast(one_chip):
+    """The scan's survivor compaction prefix-sums a row group's mask: a
+    flat ``jnp.cumsum`` of 1M values takes ~20 s to compile for the TPU,
+    the blocked one under a second (AOT, PR 21)."""
+    from parquet_tpu.ops import device as dev
+
+    mask = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    bounded(lambda: jax.jit(dev.cumsum).lower(mask).compile(), 15,
+            "compiling the blocked prefix sum")
