@@ -197,8 +197,6 @@ declare("PARQUET_TPU_SLOW_LOG", "str", "",
         "append one JSON line per slow op to this file")
 declare("PARQUET_TPU_TRACE_DIR", "str", "",
         "jax profiler output directory for profiler_trace() regions")
-declare("PARQUET_TPU_DEBUG", "bool", False,
-        "legacy call-log tracing + debug counters (utils/debug.py)")
 
 # ------------------------------------------------------ lockcheck sanitizer
 declare("PARQUET_TPU_LOCKCHECK", "bool", False,

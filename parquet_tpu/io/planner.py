@@ -348,7 +348,7 @@ class ScanPlanner:
         skip = self.policy is not None and self.policy.skip_corrupt
         plan_span = (_trace.span("planner.plan", file=self.pf._path,
                                  stages=",".join(stages))
-                     if _trace.TRACE_ENABLED else _trace.NULL_SPAN)
+                     if _trace.on() else _trace.NULL_SPAN)
         with plan_span:  # `with`: a probe raising must still close the span
             for rg in self.pf.row_groups:
                 d = RowGroupDecision(rg.index, rg.num_rows)

@@ -560,7 +560,7 @@ class PrefetchSource(Source):
         FILLED slice — a short inner read yields a short slice, which the
         serving path detects (the chain-covered fast path requires every
         window full) so uninitialized segment bytes are never served."""
-        if _trace.TRACE_ENABLED:
+        if _trace.on():
             # window fills run on pool workers: the span's thread id is
             # what makes IO/decode overlap visible on the Perfetto tracks
             with _trace.span("prefetch.window", offset=offset, bytes=size):
@@ -646,7 +646,7 @@ class PrefetchSource(Source):
             return fut.result()
         t0 = time.perf_counter()
         wait_span = (_trace.span("prefetch.wait", offset=win.offset)
-                     if _trace.TRACE_ENABLED else _trace.NULL_SPAN)
+                     if _trace.on() else _trace.NULL_SPAN)
         wait_span.__enter__()
         try:
             while True:

@@ -33,7 +33,7 @@ from ..obs.metrics import histogram as _ohistogram
 # resolved once: per-read observation must not take the registry's
 # get-or-create lock (only the metric's own)
 _M_READ_FILE_S = _ohistogram("read.file_s")
-from ..utils.debug import counters, trace
+from ..utils.debug import counters
 from .column import Column, concat_columns
 from .source import Source, as_source
 
@@ -537,7 +537,7 @@ class ParquetFile:
         counters.inc("files_opened")
 
     def _open_footer(self) -> None:
-        if _otrace.TRACE_ENABLED:
+        if _otrace.on():
             with _otrace.span("open.footer", file=self._path):
                 self._open_footer_impl()
             return
@@ -668,7 +668,7 @@ class ParquetFile:
         touching chunk bytes."""
         dec_span = (_otrace.span("decode.chunk", rg=chunk.rg_index,
                                  col=chunk.leaf.dotted_path)
-                    if _otrace.TRACE_ENABLED else _otrace.NULL_SPAN)
+                    if _otrace.on() else _otrace.NULL_SPAN)
         with dec_span, \
                 read_context(path=self._path, row_group=chunk.rg_index,
                              column=chunk.leaf.dotted_path):

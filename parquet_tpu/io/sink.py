@@ -657,7 +657,7 @@ class BufferedSink(Sink):
     def _flush_buffer(self) -> None:
         if not self._parts:
             return
-        if _trace.TRACE_ENABLED:
+        if _trace.on():
             with _trace.span("sink.flush", bytes=self._buffered,
                              parts=len(self._parts)):
                 self._flush_buffer_impl()

@@ -240,7 +240,7 @@ def _take_contextual(pf, cursor, path, rg_index, take):
     bars on different worker tracks."""
     dec_span = (_trace.span("decode.stream", rg=rg_index, col=path,
                             rows=take)
-                if _trace.TRACE_ENABLED else _trace.NULL_SPAN)
+                if _trace.on() else _trace.NULL_SPAN)
     with dec_span, \
             read_context(path=pf._path, row_group=rg_index, column=path):
         pieces, got = cursor.take(take)

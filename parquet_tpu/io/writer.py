@@ -484,7 +484,7 @@ class ParquetWriter:
         # encode/emit overlap shows as parallel bars on two tracks
         enc_span = (_otrace.span("write.encode", col=leaf.dotted_path,
                                  rows=num_rows)
-                    if _otrace.TRACE_ENABLED else _otrace.NULL_SPAN)
+                    if _otrace.on() else _otrace.NULL_SPAN)
         with enc_span:
             t0 = time.perf_counter()
             enc = self._encode_chunk(leaf, data, num_rows)
@@ -664,7 +664,7 @@ class ParquetWriter:
         total_comp = 0
         emit_span = (_otrace.span("write.emit",
                                   rg=len(self._row_groups), rows=num_rows)
-                     if _otrace.TRACE_ENABLED else _otrace.NULL_SPAN)
+                     if _otrace.on() else _otrace.NULL_SPAN)
         with emit_span:  # `with`: a failed emit must still record the span
             for enc in encs:
                 t0 = time.perf_counter()

@@ -6,9 +6,10 @@ through.
   legacy per-operation stats dataclasses (``ReadStats``, ``WriteStats``,
   ``CacheStats``, ``ReadReport``, planner counters, ``RouteHistory``)
   keep their APIs and publish here too.
-- :mod:`parquet_tpu.obs.trace` — span tracing with a module-level bool
-  gate (near-zero overhead off) writing Chrome trace-event JSON for
-  Perfetto; ``PARQUET_TPU_TRACE=/path.json`` enables per process.
+- :mod:`parquet_tpu.obs.trace` — the one span API, near-zero overhead
+  off, with two sinks: an active ``jax.profiler`` session (``pq.<name>``
+  annotations on the device's clock) and Chrome trace-event JSON for
+  Perfetto (``PARQUET_TPU_TRACE=/path.json`` enables it per process).
 - :mod:`parquet_tpu.obs.export` — Prometheus text-format rendering
   (``python -m parquet_tpu stats --prom``) and the live scrape endpoint
   (``start_metrics_server`` / ``stats --serve PORT``).
@@ -29,9 +30,9 @@ through.
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       counter, gauge, histogram, metrics_delta,
                       metrics_snapshot, pool_wait_seconds, reset_metrics)
-# NOTE: the live gate is ``trace.TRACE_ENABLED`` on the MODULE —
-# instrumentation sites import the module and read the attribute each
-# time (a re-exported copy of the bool would go stale on enable/disable)
+# NOTE: the live gate is ``trace.on()`` on the MODULE — instrumentation
+# sites import the module and call it each time (a re-exported copy of
+# ``TRACE_ENABLED`` would go stale on enable/disable)
 from . import trace
 from .trace import (NULL_SPAN, disable_tracing, enable_tracing, enabled,
                     flush_trace, reset_trace, span, trace_events,

@@ -266,7 +266,7 @@ class ResourceLedger:
 
             span = (_trace.span("ledger.pressure", state=state,
                                 total_bytes=self.total())
-                    if _trace.TRACE_ENABLED else _trace.NULL_SPAN)
+                    if _trace.on() else _trace.NULL_SPAN)
             with span:
                 self._respond()
         finally:

@@ -596,14 +596,6 @@ def _declare_core() -> None:
                        help="whole-dataset aggregation latency")
     REGISTRY.histogram("fused.fold_s",
                        help="per-row-group fused decode+mask+fold latency")
-    # device-scale dataset reads: stage/decode split so the overlap win
-    # (h2d hidden under decode) is measurable from a scrape alone
-    REGISTRY.histogram("device.h2d_s",
-                       help="per-file H2D staging latency on the "
-                            "mesh-sharded device read path")
-    REGISTRY.histogram("device.decode_s",
-                       help="per-file on-chip decode latency on the "
-                            "mesh-sharded device read path")
     # the reason axis is closed; runtime refusals outside it fold into
     # "other" (device_refusal_reason) so every series exists at 0
     for reason in ("unsupported", "policy", "budget", "error", "other"):

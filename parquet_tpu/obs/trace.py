@@ -1,47 +1,50 @@
-"""Span tracing: Chrome trace-event JSON you can drop into Perfetto.
+"""Span tracing: one span API, two sinks.
 
-PR 3–6 shipped pipeline claims — encode/emit overlap ratios, prefetch
-bubbles, late-materialization skips — as *numbers* in stats dataclasses.
-This module makes them *visible*: every load-bearing stage (footer open,
-prefetch window issue/wait, per-column decode, planner cascade, host-scan
-phase 1/2, encode/emit, sink flush, H2D staging, pool task queue→run) is
-wrapped in a :func:`trace_span`, each completed span records its
-worker-thread id, and the buffer flushes to the Chrome ``traceEvents``
-JSON format (Perfetto / ``chrome://tracing`` load it directly) — so
-pipeline overlap shows up as literally overlapping bars on different
-thread tracks.
+:func:`span` is the only way the package opens a span.  It writes to
+whichever sinks are on:
 
-Overhead contract — tracing OFF is the production default and must cost
-nothing measurable:
+- **A profiler session** (``jax.profiler.start_trace`` or
+  ``jax.profiler.trace``): every span is also a ``TraceAnnotation`` named
+  ``"pq." + name`` and nothing else, so it lands in the ``.xplane.pb`` on
+  the device's clock, beside the device programs it dispatches, and a
+  trace reader can match it by name.
+- **The Chrome-JSON buffer** (``PARQUET_TPU_TRACE`` or
+  :func:`enable_tracing`): every load-bearing stage (footer open, prefetch
+  window issue/wait, per-column decode, planner cascade, host-scan phase
+  1/2, encode/emit, sink flush, H2D staging, pool task queue->run) records
+  its worker-thread id, and the buffer flushes to the Chrome
+  ``traceEvents`` JSON format (Perfetto / ``chrome://tracing`` load it
+  directly), so pipeline overlap shows up as overlapping bars on
+  different thread tracks.  Names here carry no prefix.
+  ``PARQUET_TPU_TRACE=/path/trace.json`` (env, read at import) turns it on
+  for the process and flushes the buffer to that path at interpreter
+  exit; :func:`enable_tracing`/:func:`disable_tracing`/:func:`flush_trace`
+  are the programmatic controls (tests, notebooks).
 
-- ``TRACE_ENABLED`` is a module-level bool.  The hottest sites read it
-  directly (``if trace.TRACE_ENABLED:``) and skip span construction
-  entirely.
-- :func:`trace_span` called while disabled returns one shared no-op
-  singleton — no object allocation, no timestamps, no lock.
+Overhead contract: with both sinks off (the production default) a span
+costs nothing measurable:
 
-Enabling:
+- :func:`on` reads both gates: ``TRACE_ENABLED``, a module-level bool, and
+  ``TraceAnnotation.is_enabled()``, about 60 ns.  Sites that build span
+  attributes guard with ``if trace.on():`` and skip even that work.
+- :func:`span` called while both are off returns one shared no-op
+  singleton: no object allocation, no timestamps, no lock.
 
-- ``PARQUET_TPU_TRACE=/path/trace.json`` (env, read at import): tracing
-  on for the process, buffer flushed to that path at interpreter exit.
-- :func:`enable_tracing`/:func:`disable_tracing`/:func:`flush_trace` —
-  the programmatic controls (tests, notebooks).
+The Chrome-JSON event buffer is bounded (:data:`MAX_EVENTS`); overflow
+drops new events and counts them in the ``trace.events_dropped`` metric
+instead of growing without bound.  While that sink is on, each completed
+span also feeds a ``span.<name>_s`` latency histogram in the metrics
+registry, so stage p50/p99 come for free with a traced run.
 
-The event buffer is bounded (:data:`MAX_EVENTS`); overflow drops new
-events and counts them in the ``trace.events_dropped`` metric instead of
-growing without bound.  While tracing is on, each completed span also
-feeds a ``span.<name>_s`` latency histogram in the metrics registry, so
-stage p50/p99 come for free with a traced run.
-
-Request scopes (obs/scope.py) route spans through two context variables
-here: ``_TRACK`` gives every span of an operation the op's own Perfetto
-"process" track (pid = op id, named by a one-time ``process_name``
-metadata event), and ``_SINK`` — set for ops head-sampling decided NOT to
-trace — diverts completed spans into a per-op :class:`OpRing` that is
-promoted to the global buffer only if the op turns out slow (tail
-capture) and discarded allocation-cheap otherwise.  Both are
-``contextvars``, so pool workers running an op's tasks inherit them via
-the context propagation in ``utils/pool.instrument_task``.
+Request scopes (obs/scope.py) route Chrome-JSON spans through two context
+variables here: ``_TRACK`` gives every span of an operation the op's own
+Perfetto "process" track (pid = op id, named by a one-time
+``process_name`` metadata event), and ``_SINK`` -- set for ops head
+sampling decided NOT to trace -- diverts completed spans into a per-op
+:class:`OpRing` that is promoted to the global buffer only if the op turns
+out slow (tail capture) and discarded allocation-cheap otherwise.  Both
+are ``contextvars``, so pool workers running an op's tasks inherit them
+via the context propagation in ``utils/pool.instrument_task``.
 """
 
 from __future__ import annotations
@@ -55,12 +58,14 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..utils.env import env_str
 from ..utils.locks import make_lock
 from . import metrics as _metrics
 from .ledger import ledger_account as _ledger_account
 
-__all__ = ["TRACE_ENABLED", "trace_span", "span", "enabled",
+__all__ = ["TRACE_ENABLED", "trace_span", "span", "on", "enabled",
            "enable_tracing", "disable_tracing", "flush_trace",
            "trace_events", "reset_trace", "MAX_EVENTS", "OpRing",
            "promote_ring", "emit_op_event"]
@@ -96,6 +101,11 @@ _SINK: "contextvars.ContextVar[Optional[OpRing]]" = \
 # stage-breakdown hook, bound by obs/scope.py at import: called as
 # (span_name, duration_s) for every completed span while tracing is on
 _ON_SPAN = None
+# the profiler-session gate (~60 ns when no session is active)
+_PROFILING = _Annotation.is_enabled
+# what a span is called on the profiler's timeline: trace readers match
+# program spans by this prefix
+PROFILER_PREFIX = "pq."
 
 
 class _NullSpan:
@@ -136,22 +146,29 @@ def _span_hist(name: str):
 
 
 class _Span:
-    """One enabled span: perf_counter timestamps, the worker thread id it
-    ran on, and a Chrome complete ("X") event on exit."""
+    """One Chrome-JSON span: perf_counter timestamps, the worker thread id
+    it ran on, and a Chrome complete ("X") event on exit; inside a profiler
+    session it also opens the span's ``pq.`` annotation."""
 
-    __slots__ = ("name", "attrs", "_t0", "_tid")
+    __slots__ = ("name", "attrs", "_t0", "_tid", "_ann")
 
     def __init__(self, name: str, attrs: Optional[Dict] = None):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
+        self._ann = None
+        if _PROFILING():
+            self._ann = _Annotation(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
         self._tid = threading.get_ident()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         if not TRACE_ENABLED:  # disabled mid-span: nothing to record into
             return False
         dur = t1 - self._t0
@@ -273,17 +290,28 @@ def emit_op_event(name: str, track, t0: float, dur_s: float,
 
 
 def enabled() -> bool:
+    """Whether the Chrome-JSON sink is on."""
     return TRACE_ENABLED
 
 
+def on() -> bool:
+    """Whether any span sink is on: the Chrome-JSON buffer or a profiler
+    session.  Sites that build span attributes guard with it."""
+    return TRACE_ENABLED or _PROFILING()
+
+
 def trace_span(name: str, **attrs):
-    """Context manager for one traced stage: ``with trace_span("decode",
-    col="x"): ...``.  With tracing disabled this returns the shared no-op
-    singleton — the hottest call sites additionally guard with
-    ``if trace.TRACE_ENABLED:`` to skip even the call."""
-    if not TRACE_ENABLED:
-        return NULL_SPAN
-    return _Span(name, attrs or None)
+    """Context manager for one traced stage: ``with span("decode",
+    col="x"): ...``.  With the Chrome-JSON sink on it records a ``name``
+    event (and, inside a profiler session, a ``pq.<name>`` annotation);
+    inside a profiler session alone it is that annotation, without the
+    attributes, so the event's name is exactly ``pq.<name>``; with both
+    sinks off it is the shared no-op singleton."""
+    if TRACE_ENABLED:
+        return _Span(name, attrs or None)
+    if _PROFILING():
+        return _Annotation(PROFILER_PREFIX + name)
+    return NULL_SPAN
 
 
 span = trace_span  # the short form instrumentation sites import

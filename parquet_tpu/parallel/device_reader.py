@@ -42,6 +42,7 @@ from ..format import metadata as md
 from ..format.enums import CompressionCodec, Encoding, PageType, Type
 from ..io.column import Column
 from ..io.reader import ColumnChunkReader, CorruptedError, decode_chunk_host, _bit_width
+from ..obs import trace as _otrace
 from ..ops import device as dev, levels as levels_ops, ref
 from ..utils.debug import counters
 from .. import native
@@ -110,8 +111,13 @@ class _RunTable:
     bit_offsets: List[np.ndarray] = field(default_factory=list)
     widths: List[np.ndarray] = field(default_factory=list)
     total: int = 0
+    # encoded bytes of the hybrid streams the runs were scanned from: with
+    # ``total`` the logical work of one expand, fixed by the data
+    nbytes: int = 0
 
-    def add_scanned(self, kinds, cnts, payloads, offs, width, base_byte, n):
+    def add_scanned(self, kinds, cnts, payloads, offs, width, base_byte, n,
+                    nbytes: int):
+        self.nbytes += nbytes
         self.kinds.append(kinds)
         self.payloads.append(payloads)
         self.bit_offsets.append((offs + base_byte) * 8)
@@ -132,11 +138,13 @@ class _RunTable:
         else:
             kinds, cnts, payloads, offs, _end = ref.scan_rle_runs(
                 data, n, width, 0)
-        self.add_scanned(kinds, cnts, payloads, offs, width, base_byte, n)
+        self.add_scanned(kinds, cnts, payloads, offs, width, base_byte, n,
+                         len(data))
         return kinds, cnts, payloads, offs
 
     def add_bitpacked_span(self, n: int, width: int, base_byte: int):
         """A raw bit-packed span (e.g. PLAIN BOOLEAN page) as a single run."""
+        self.nbytes += (n * width + 7) // 8
         self.kinds.append(np.ones(1, np.uint8))
         self.payloads.append(np.zeros(1, np.int64))
         self.bit_offsets.append(np.array([base_byte * 8], np.int64))
@@ -167,7 +175,9 @@ class _RunTable:
 
     def expand(self, dbuf: jax.Array, n: Optional[int] = None,
                tables: Optional[tuple] = None) -> jax.Array:
-        return dev.rle_expand(dbuf, n or self.total,
+        n = n or self.total
+        counters.inc("kernel_bytes.rle_expand", self.nbytes + 4 * n)
+        return dev.rle_expand(dbuf, n,
                               *(tables if tables is not None
                                 else self.run_arrays()))
 
@@ -465,7 +475,8 @@ def _fused_dict_plan(reader: ColumnChunkReader):
                 raw, np.uint8)
             payload = rawv[row[native.PG_DATA_POS]:
                            row[native.PG_DATA_POS] + row[native.PG_COMP]]
-            dbody = reader.codec.decode(payload, int(row[native.PG_UNCOMP]))
+            dbody = _decompress(reader.codec, payload,
+                                int(row[native.PG_UNCOMP]))
             plan.dictionary_host = ref.decode_plain(
                 np.frombuffer(dbody, np.uint8),
                 int(row[native.PG_DICT_NVALS]), physical, leaf.type_length)
@@ -477,11 +488,19 @@ def _fused_dict_plan(reader: ColumnChunkReader):
     v.bit_offsets.append(bit_offs)
     v.widths.append(widths)
     v.total = nvals
+    v.nbytes = len(body)
     plan.values.extend(body)
     plan.total_slots = nvals   # all-present proven by the native scan
     plan.total_values = nvals
     counters.inc("fused_dict_plans")
     return plan, raw
+
+
+def _decompress(codec, payload, size: int):
+    """One page's ``codec.decode`` inside a ``decompress`` span: the host
+    codec time of the staging phase."""
+    with _otrace.span("decompress"):
+        return codec.decode(payload, size)
 
 
 def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
@@ -508,7 +527,7 @@ def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
         h = page.header
         pt = page.page_type
         if pt == PageType.DICTIONARY_PAGE:
-            raw = codec.decode(page.payload, h.uncompressed_page_size)
+            raw = _decompress(codec, page.payload, h.uncompressed_page_size)
             plan.dictionary_host = ref.decode_plain(
                 np.frombuffer(raw, np.uint8), h.dictionary_page_header.num_values,
                 physical, leaf.type_length)
@@ -516,7 +535,8 @@ def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
         if pt == PageType.DATA_PAGE:
             dph = h.data_page_header
             n = dph.num_values
-            raw = np.frombuffer(codec.decode(page.payload, h.uncompressed_page_size), np.uint8)
+            raw = np.frombuffer(_decompress(codec, page.payload,
+                                            h.uncompressed_page_size), np.uint8)
             pos = 0
             n_present = n
             if max_rep > 0:
@@ -561,7 +581,8 @@ def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
                 plan.levels.extend(page.payload[rl : rl + dl])
             raw_body = page.payload[rl + dl :]
             if dph2.is_compressed is not False:
-                raw_body = codec.decode(raw_body, h.uncompressed_page_size - rl - dl)
+                raw_body = _decompress(codec, raw_body,
+                                       h.uncompressed_page_size - rl - dl)
             raw = np.frombuffer(raw_body, np.uint8)
             n_present = n - (dph2.num_nulls or 0)
             _stage_values(plan, raw, 0, n_present, Encoding(dph2.encoding),
@@ -709,7 +730,7 @@ def _stage_values(plan: _Plan, raw: np.ndarray, pos: int, nvals: int,
         if width == 0:  # single-entry dictionary
             plan.vruns.add_scanned(np.zeros(1, np.uint8), np.array([nvals]),
                                    np.zeros(1, np.int64), np.zeros(1, np.int64),
-                                   1, base, nvals)
+                                   1, base, nvals, len(body))
             plan.dense_ok = False
         else:
             kinds, cnts, _, offs = plan.vruns.add(body, nvals, width, base)
@@ -1059,9 +1080,7 @@ def stage_plan(plan: _Plan, stage_levels: bool = True, put=None) -> tuple:
     ``jax.device_put`` — :func:`prepare_chunks_batched` passes a recorder so
     many chunks' streams ride one batched transfer.
     """
-    from ..obs import trace as _otrace
-
-    if _otrace.TRACE_ENABLED:
+    if _otrace.on():
         # the H2D stage is the device pipeline's overlap partner: its span
         # sitting beside a decode span on another track IS the double
         # buffer working
@@ -1201,9 +1220,7 @@ def prepare_chunk(reader: ColumnChunkReader, device=None):
     the put at a specific mesh device."""
     import contextlib
 
-    from ..utils.debug import annotate
-
-    with annotate("pq.prepare_chunk"):
+    with _otrace.span("prepare_chunk"):
         plan = build_plan(reader)
         ctx = (jax.default_device(device) if device is not None
                else contextlib.nullcontext())
@@ -1253,8 +1270,6 @@ def prepare_chunks_batched(readers, device=None):
     Returns ``[(reader, (plan, staged) | None, error)]`` in input order —
     the per-chunk triple ``decode``-side consumers already handle, with
     ``_Unsupported`` chunks carried as errors rather than raised."""
-    from ..utils.debug import annotate
-
     calls: list = []
 
     def put(x):
@@ -1262,7 +1277,7 @@ def prepare_chunks_batched(readers, device=None):
         return _DeferredPut(len(calls) - 1)
 
     entries = []
-    with annotate("pq.prepare_chunks_batched"):
+    with _otrace.span("prepare_chunks_batched"):
         for reader in readers:
             try:
                 plan = build_plan(reader)
@@ -1540,9 +1555,8 @@ def decode_chunk_device(reader: ColumnChunkReader, keep_dictionary: bool = True,
 def decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
                   keep_dictionary: bool = True) -> Column:
     """Device decode phase: staged HBM buffers → decoded :class:`Column`."""
-    from ..utils.debug import annotate
-
-    with annotate(f"pq.decode_staged:{plan.value_kind}"):
+    with (_otrace.span(f"decode_staged:{plan.value_kind}") if _otrace.on()
+          else _otrace.NULL_SPAN):
         return _decode_staged(leaf, physical, plan, staged, keep_dictionary)
 
 
@@ -1645,6 +1659,7 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
                 dt = np.int32 if physical == Type.INT32 else np.float32
                 values = arr[: nvals * 4].view(dt)
         elif physical in _IS_PAIR:
+            counters.inc("kernel_bytes.fixed64_pairs", 16 * nvals)
             values = dev.fixed64_pairs(val_dbuf, nvals)
         elif physical == Type.INT96:
             values = dev.bitcast_rows(val_dbuf, 12, jnp.uint32)[:nvals]

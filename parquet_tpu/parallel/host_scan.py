@@ -437,7 +437,7 @@ def _scan_expr_impl(pf, where, columns, num_threads, use_bloom, pol,
 
     p1_span = (_otrace.span("scan.phase1", file=pf._path,
                             spans=len(spans), cand_rows=cand_rows)
-               if _otrace.TRACE_ENABLED else _otrace.NULL_SPAN)
+               if _otrace.on() else _otrace.NULL_SPAN)
     # `with`: a failing fan-out (deadline, unskippable corruption) must
     # still record the span — the failed run is the one worth tracing
     with p1_span:
@@ -493,7 +493,7 @@ def _scan_expr_impl(pf, where, columns, num_threads, use_bloom, pol,
                  for t0, t1 in [t]) * max(len(read2_cols), 1)
     p2_span = (_otrace.span("scan.phase2", file=pf._path,
                             tasks=len(tasks2), cells=cells2)
-               if _otrace.TRACE_ENABLED else _otrace.NULL_SPAN)
+               if _otrace.on() else _otrace.NULL_SPAN)
     with p2_span:  # `with`: record the span even when the fan-out raises
         res2 = fan_out(read_one, tasks2, cells2)
     failures = [r for r in res2 if isinstance(r, _SpanFailure)]
@@ -754,7 +754,10 @@ def stage_scan(pf: ParquetFile, path: str, lo=None, hi=None,
     from ..io.prefetch import make_chunk_prefetcher
 
     pol, report = resolve_policy(pf, policy, report)
-    with pf._resilient_op(policy, report, "stage_scan"):
+    # one span over the whole staging phase (pruning, then every chunk's
+    # pread, decompress, prescan and H2D enqueue)
+    with _otrace.span("stage_scan"), \
+            pf._resilient_op(policy, report, "stage_scan"):
         # device-route prefetch (ROADMAP follow-on, PR 3): surviving spans'
         # chunk ranges are planned through an advise-backed prefetcher so
         # kernel readahead of later chunks overlaps prescan + H2D of
@@ -1333,8 +1336,9 @@ def _scan_routed(pf, path, lo, hi, columns, use_bloom, values, policy,
     from ..io.planner import route_history, route_scan
 
     pol = policy if policy is not None else pf.policy
-    decision = route_scan(pf, path, lo=lo, hi=hi, columns=columns,
-                          values=values)
+    with _otrace.span("route"):
+        decision = route_scan(pf, path, lo=lo, hi=hi, columns=columns,
+                              values=values)
     t0 = time.monotonic()
     w0 = _pool_wait_seconds()
     if decision.route == "device":

@@ -31,7 +31,7 @@ from ..format.enums import Encoding
 from ..io.column import Column
 from ..io.reader import ParquetFile
 from ..obs.ledger import ledger_account
-from ..obs.metrics import counter as _ocounter, histogram as _ohistogram
+from ..obs.metrics import counter as _ocounter
 from ..obs.scope import account as _oaccount
 from ..ops import device as dev
 from ..utils import pool as _pool
@@ -40,8 +40,6 @@ from ..utils.env import env_str
 
 # resolved once at import (hot-path rule: no registry get-or-create per
 # file); the ledger account is owned HERE (analysis/lint.py PT003)
-_M_H2D_S = _ohistogram("device.h2d_s")
-_M_DECODE_S = _ohistogram("device.decode_s")
 _M_FILES_SHARDED = _ocounter("device.files_sharded")
 _M_STAGE_OVERLAPPED = _ocounter("device.stage_overlapped")
 _ACC_STAGING = ledger_account("device.staging")
@@ -569,7 +567,8 @@ def read_table_sharded(source, mesh: Optional[Mesh] = None,
 
 
 def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
-    """Build a jitted, mesh-sharded batched decode step.
+    """Build a jitted, mesh-sharded batched decode step; each call also
+    counts its kernels' logical bytes (``kernel_bytes.*`` counters).
 
     Input: per-device staging buffers ``bytes_in [n_dev, B]`` (uint8, each
     device's batch of PLAIN INT64 page bytes), level buffers and run tables
@@ -596,12 +595,24 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
         nrows = jax.lax.psum(jnp.sum(validity.astype(jnp.int32)), axis)
         return lo[None], hi[None], validity[None], nrows
 
-    sharded = jax.shard_map(
+    sharded = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(spec,) * 7,
         out_specs=(spec, spec, spec, rep),
-        check_vma=False)
-    return jax.jit(sharded)
+        check_vma=False))
+    n_dev = mesh.shape[axis]
+
+    def call(vbuf, lbuf, *runs):
+        # the kernels' logical bytes, counted per call here: inside the
+        # traced step they would count once per trace.  The step is handed
+        # no unpadded level-stream length, so its encoded level bytes are
+        # the staged buffers'
+        counters.inc("kernel_bytes.fixed64_pairs", 16 * n_per_shard * n_dev)
+        counters.inc("kernel_bytes.rle_expand",
+                     int(np.prod(lbuf.shape)) + 4 * n_per_shard * n_dev)
+        return sharded(vbuf, lbuf, *runs)
+
+    return call
 
 
 # ---------------------------------------------------------------------------
@@ -692,7 +703,6 @@ def _stage_dataset_file(dataset, i: int, columns, device) -> _FileStage:
     st = _FileStage(index=i, pf=pf, leaves=leaves, rg_sel=rg_sel,
                     device=device, est_bytes=est, grant=grant)
     try:
-        t0 = time.perf_counter()
         with contextlib.ExitStack() as stack:
             pre = make_chunk_prefetcher(pf.source,
                                         n_streams=min(len(chunks), 4) or 1)
@@ -704,7 +714,6 @@ def _stage_dataset_file(dataset, i: int, columns, device) -> _FileStage:
             # file's chip — per-chunk H2D dispatch overhead scales with
             # row-group count, and the mesh route amortizes it per file
             st.preps.extend(prepare_chunks_batched(chunks, device=device))
-        _M_H2D_S.observe(time.perf_counter() - t0)
     except BaseException:
         st.release()
         raise
@@ -724,7 +733,6 @@ def _decode_dataset_file(st: _FileStage):
     if not st.rg_sel:
         return Table(pf.schema, {leaf.dotted_path: empty_column(leaf)
                                  for leaf in st.leaves}, 0)
-    t0 = time.perf_counter()
     n_rg = len(st.rg_sel)
     it = iter(st.preps)
     parts: Dict[str, list] = {}
@@ -743,9 +751,7 @@ def _decode_dataset_file(st: _FileStage):
                         col, _nn = _decode_prepped(reader, prep)
                 cols.append(col)
             parts[leaf.dotted_path] = cols
-    tbl = Table(pf.schema, None, pf.num_rows, parts=parts)
-    _M_DECODE_S.observe(time.perf_counter() - t0)
-    return tbl
+    return Table(pf.schema, None, pf.num_rows, parts=parts)
 
 
 def read_dataset_device(dataset, columns=None, with_reports: bool = False,

@@ -1,23 +1,17 @@
-"""Observability: counters + env-gated call tracing.
+"""Debug counters and the profiler-trace region.
 
-Reference parity: ``internal/debug`` wraps readers/writers with call logging
-gated by an env var (SURVEY.md §5) — the reference's entire observability
-story.  New-framework additions per §5: lightweight counters (pages decoded,
-bytes H2D, kernel launches) behind ``PARQUET_TPU_DEBUG``.
+Lightweight always-on counters (chunks decoded, bytes H2D, kernel bytes)
+exported as ``parquet_tpu.counters``; spans go through
+:func:`parquet_tpu.obs.trace.span`.
 """
 
 from __future__ import annotations
 
-import functools
-import sys
-import time
 from collections import defaultdict
 from typing import Optional
 
-from .env import env_bool, env_str
+from .env import env_str
 from .locks import make_lock
-
-DEBUG = env_bool("PARQUET_TPU_DEBUG")
 
 
 class Counters:
@@ -52,23 +46,6 @@ class Counters:
 counters = Counters()
 
 
-def trace(fn):
-    """Log calls + wall time when PARQUET_TPU_DEBUG is set (else zero-cost)."""
-    if not DEBUG:
-        return fn
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        t0 = time.perf_counter()
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            dt = (time.perf_counter() - t0) * 1e3
-            print(f"[parquet-tpu] {fn.__qualname__} {dt:.3f}ms", file=sys.stderr)
-
-    return wrapper
-
-
 def profiler_trace(out_dir: Optional[str] = None):
     """Context manager: capture a ``jax.profiler`` trace (Perfetto/XPlane)
     around a decode/scan region — SURVEY.md §5's jax.profiler + Perfetto
@@ -91,15 +68,3 @@ def profiler_trace(out_dir: Optional[str] = None):
 
     return jax.profiler.trace(out_dir)
 
-
-def annotate(name: str):
-    """Named profiler region (jax.profiler.TraceAnnotation when available;
-    no-op otherwise) for attributing device work inside a profiler_trace."""
-    import contextlib
-
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()

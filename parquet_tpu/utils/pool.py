@@ -194,7 +194,7 @@ def _run_instrumented(fn, name, t_submit: float, a, k):
     _scope.account(_TASKS)
     _ACTIVE.inc()  # the /debugz "running now" meter
     try:
-        if _trace.TRACE_ENABLED:
+        if _trace.on():
             with _trace.span("pool.task", fn=name):
                 return fn(*a, **k)
         return fn(*a, **k)

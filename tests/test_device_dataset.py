@@ -92,9 +92,6 @@ def test_device_read_byte_identical_across_encodings(tmp_path):
     # every file really took the sharded device route (no silent host
     # rerouting of the whole corpus)
     assert delta["counters"].get("device.files_sharded", 0) == N_FILES
-    assert delta["histograms"].get("device.h2d_s", {}).get("count") == N_FILES
-    assert delta["histograms"].get("device.decode_s", {}).get(
-        "count") == N_FILES
 
 
 def test_device_read_column_selection_and_single_file(tmp_path):

@@ -560,3 +560,65 @@ def test_device_asm_default_is_backend_aware(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert dr.stage_levels_on_device(leaf, plan) is True
+
+
+def _kernel_bytes_from_metadata(raw: bytes) -> dict:
+    """The logical bytes of ``rle_expand`` and ``fixed64_pairs`` for one
+    device read, from the file's page headers alone (DATA_PAGE_V2 carries
+    the level-stream lengths): per flat nullable column, its def-level
+    bytes + 4 x slots when it holds nulls; per dictionary column its index
+    bytes (the value section past the bit-width byte) + 4 x present values;
+    per PLAIN 8-byte column 16 x present values."""
+    from parquet_tpu.format.enums import Encoding, PageType, Type
+
+    pf = ParquetFile(raw)
+    rle = pairs = 0
+    for g in range(len(pf.row_groups)):
+        for leaf in pf.schema.leaves:
+            reader = pf.row_group(g).column(leaf.column_index)
+            v2 = [p.header for p in reader.pages()
+                  if p.page_type == PageType.DATA_PAGE_V2]
+            slots = sum(h.data_page_header_v2.num_values for h in v2)
+            nulls = sum(h.data_page_header_v2.num_nulls for h in v2)
+            if nulls:
+                rle += sum(h.data_page_header_v2.definition_levels_byte_length
+                           for h in v2) + 4 * slots
+            for h in v2:
+                d = h.data_page_header_v2
+                present = d.num_values - d.num_nulls
+                if Encoding(d.encoding) == Encoding.RLE_DICTIONARY:
+                    rle += (h.uncompressed_page_size
+                            - d.definition_levels_byte_length
+                            - d.repetition_levels_byte_length - 1)
+                    rle += 4 * present
+                elif leaf.physical_type in (Type.INT64, Type.DOUBLE):
+                    pairs += 16 * present
+    return {"kernel_bytes.rle_expand": rle,
+            "kernel_bytes.fixed64_pairs": pairs}
+
+
+def test_kernel_byte_counters_match_the_file_metadata(monkeypatch, rng):
+    """``kernel_bytes.*`` count the work the data fixes: two reads of one
+    file count the same bytes, and those are the page headers' formula."""
+    from parquet_tpu import counters
+
+    for knob in ("PARQUET_TPU_PLAIN_RUNS", "PARQUET_TPU_DICT_RUNS"):
+        monkeypatch.setenv(knob, "device")
+    monkeypatch.setenv("PARQUET_TPU_PALLAS", "off")  # indices via the runs
+    n = 6000
+    t = pa.table({
+        "i64": pa.array(rng.integers(-(2**60), 2**60, n)),
+        "f64n": pa.array(rng.random(n), mask=rng.random(n) < 0.2),
+        "d32n": pa.array(rng.integers(0, 50, n).astype(np.int32),
+                         mask=rng.random(n) < 0.3),
+    })
+    raw = _write(t, use_dictionary=["d32n"], data_page_version="2.0",
+                 row_group_size=2500, data_page_size=4096)
+    want = _kernel_bytes_from_metadata(raw)
+    assert all(want.values())
+    for _ in range(2):
+        before = counters.snapshot()
+        _check(raw, t)
+        after = counters.snapshot()
+        got = {k: after.get(k, 0) - before.get(k, 0) for k in want}
+        assert got == want

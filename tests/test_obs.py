@@ -253,6 +253,76 @@ def test_flush_without_path_returns_none():
         assert obs.flush_trace() is None
 
 
+def _profiler_span_names(log_dir):
+    """Host event names of the one ``.xplane.pb`` under ``log_dir``."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_spans_land_in_a_profiler_session(tmp_path):
+    """Inside a jax.profiler session a span is a ``pq.<name>`` annotation,
+    exactly (no attributes in the name), from the main thread and from a
+    shared-pool worker alike."""
+    import jax
+
+    from parquet_tpu.obs import trace as trace_mod
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace_mod.on() and not obs.enabled()
+        with obs.span("t.main", rg=1):
+            pass
+
+        def worker():
+            with obs.span("t.pool", col="a"):
+                return threading.get_ident()
+
+        assert pool_mod.submit(worker).result() != threading.get_ident()
+    finally:
+        jax.profiler.stop_trace()
+    assert not trace_mod.on()
+    names = _profiler_span_names(str(tmp_path))
+    assert names.count("pq.t.main") == 1
+    assert names.count("pq.t.pool") == 1
+    assert "pq.pool.task" in names  # the pool's own span, same sink
+    assert obs.trace_events() == []  # the Chrome-JSON sink stayed off
+
+
+def test_span_is_null_with_both_sinks_off():
+    from parquet_tpu.obs import trace as trace_mod
+
+    assert not trace_mod.on()
+    assert trace_mod.span("x") is NULL_SPAN
+    assert trace_mod.span("x", col="a", rows=3) is NULL_SPAN
+
+
+def test_chrome_json_names_carry_no_prefix_in_either_session(tmp_path):
+    """The Chrome-JSON sink keeps the bare name; with a profiler session on
+    as well, the same span also reaches the profiler as ``pq.<name>``."""
+    import jax
+
+    obs.enable_tracing()
+    with obs.span("x", col="a"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("x2"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    obs.disable_tracing()
+    chrome = [e["name"] for e in obs.trace_events() if e["ph"] == "X"]
+    assert chrome == ["x", "x2"]
+    assert _profiler_span_names(str(tmp_path)).count("pq.x2") == 1
+
+
 # ------------------------------------------------------- end-to-end traces
 
 def test_traced_dataset_scan_acceptance(tmp_path, monkeypatch):
