@@ -78,17 +78,19 @@ _SCAN_BLOCK = 1024
 
 
 def cumsum(x: jax.Array) -> jax.Array:
-    """Inclusive prefix sum of a 1-D array (``jnp.cumsum`` semantics and
-    dtype), blocked: the TPU compiler takes ~20 s over a flat cumsum of 1M
-    values and under a second over rows of 1,024 plus a cumsum of the row
-    totals (recursively, for very long inputs)."""
-    n = x.shape[0]
+    """Inclusive prefix sum along the last axis (``jnp.cumsum`` semantics
+    and dtype; leading axes are independent rows), blocked: the TPU
+    compiler takes ~20 s over a flat cumsum of 1M values and under a second
+    over rows of 1,024 plus a cumsum of the row totals (recursively, for
+    very long inputs)."""
+    n, lead = x.shape[-1], x.shape[:-1]
     if n <= _SCAN_BLOCK:
-        return jnp.cumsum(x)
-    rows = jnp.pad(x, (0, -n % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK)
-    inner = jnp.cumsum(rows, axis=1)
-    tot = inner[:, -1]
-    return (inner + (cumsum(tot) - tot)[:, None]).reshape(-1)[:n]
+        return jnp.cumsum(x, axis=-1)
+    pad = ((0, 0),) * len(lead) + ((0, -n % _SCAN_BLOCK),)
+    rows = jnp.pad(x, pad).reshape(*lead, -1, _SCAN_BLOCK)
+    inner = jnp.cumsum(rows, axis=-1)
+    tot = inner[..., -1]
+    return (inner + (cumsum(tot) - tot)[..., None]).reshape(*lead, -1)[..., :n]
 
 
 def _word_at(bit_starts: jax.Array):
@@ -209,19 +211,29 @@ def rle_expand(
     run_bit_offsets: jax.Array,  # int32/int64[k] absolute bit offset of packed data
     run_widths: jax.Array,  # int32[k] bit width (per run: pages may differ!)
 ) -> jax.Array:
-    """Expand a pre-scanned hybrid stream (levels / dict indexes, ≤32-bit):
-    one gather-driven pass, no sequential dependencies.  int32 out."""
+    """Expand a pre-scanned hybrid stream (levels / dict indexes, ≤32-bit).
+    int32 out; past the last run's end (a padded ``n``) the last run goes on.
+
+    No per-value search and no run-table gather: each run's attributes
+    reach its values as deltas scattered at the run's start (k-sized) and
+    one blocked prefix sum, so value ``i`` of run ``r`` sees ``r``'s
+    attributes (zero-length runs add two deltas at one start and cancel).
+    Its bits sit at ``base[r] + i * w[r]`` with ``base[r] = bit_offset[r] -
+    start[r] * w[r]`` in wrapping int32, and an RLE run has ``w = 0``, so
+    the value is ``payload + unpack(bit_pos, w)``: the two word fetches are
+    the only n-wide gathers."""
     ends = run_ends.astype(jnp.int32)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    run_id = jnp.searchsorted(ends, idx, side="right")
-    run_id = jnp.minimum(run_id, ends.shape[0] - 1).astype(jnp.int32)
-    counts = jnp.diff(ends, prepend=jnp.int32(0))
-    starts = ends[run_id] - counts[run_id]
-    within = idx - starts
-    w = run_widths[run_id]
-    bit_pos = run_bit_offsets[run_id].astype(jnp.int32) + within * w
-    packed = unpack_bits_at32(buf, bit_pos, w).astype(jnp.int32)
-    return jnp.where(run_kinds[run_id] == 0, run_payloads[run_id], packed)
+    starts = jnp.pad(ends, (1, 0))[:-1]
+    packed = run_kinds != 0
+    w = jnp.where(packed, run_widths.astype(jnp.int32), 0)
+    base = run_bit_offsets.astype(jnp.int32) - starts * w
+    payload = jnp.where(packed, 0, run_payloads.astype(jnp.int32))
+    deltas = jnp.diff(jnp.stack([base, w, payload]), axis=1, prepend=0)
+    carried = cumsum(jnp.zeros((3, n), jnp.int32).at[:, starts].add(
+        deltas, mode="drop", indices_are_sorted=True))
+    base_i, w_i, payload_i = carried
+    bit_pos = base_i + jnp.arange(n, dtype=jnp.int32) * w_i
+    return payload_i + unpack_bits_at32(buf, bit_pos, w_i).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -176,10 +176,10 @@ class _RunTable:
     def expand(self, dbuf: jax.Array, n: Optional[int] = None,
                tables: Optional[tuple] = None) -> jax.Array:
         n = n or self.total
+        tables = tables if tables is not None else self.run_arrays()
         counters.inc("kernel_bytes.rle_expand", self.nbytes + 4 * n)
-        return dev.rle_expand(dbuf, n,
-                              *(tables if tables is not None
-                                else self.run_arrays()))
+        counters.inc("kernel_runs.rle_expand", int(tables[0].shape[0]))
+        return dev.rle_expand(dbuf, n, *tables)
 
     def expand_host(self, buf: np.ndarray, n: Optional[int] = None) -> np.ndarray:
         """Numpy twin of :meth:`expand` over the host copy of the byte stream.

@@ -568,7 +568,8 @@ def read_table_sharded(source, mesh: Optional[Mesh] = None,
 
 def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
     """Build a jitted, mesh-sharded batched decode step; each call also
-    counts its kernels' logical bytes (``kernel_bytes.*`` counters).
+    counts its kernels' logical bytes (``kernel_bytes.*`` counters) and
+    the runs its ``rle_expand`` scatters (``kernel_runs.rle_expand``).
 
     Input: per-device staging buffers ``bytes_in [n_dev, B]`` (uint8, each
     device's batch of PLAIN INT64 page bytes), level buffers and run tables
@@ -610,6 +611,7 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
         counters.inc("kernel_bytes.fixed64_pairs", 16 * n_per_shard * n_dev)
         counters.inc("kernel_bytes.rle_expand",
                      int(np.prod(lbuf.shape)) + 4 * n_per_shard * n_dev)
+        counters.inc("kernel_runs.rle_expand", int(np.prod(runs[0].shape)))
         return sharded(vbuf, lbuf, *runs)
 
     return call

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from parquet_tpu.ops import device, ref
+from parquet_tpu.parallel.device_reader import _RunTable
 
 
 def _pad(b) -> np.ndarray:
@@ -59,26 +60,75 @@ def test_unpack_bits_64(w, rng):
     np.testing.assert_array_equal(got, v)
 
 
-@pytest.mark.parametrize("w", [1, 3, 8, 12, 20, 31])
-@pytest.mark.parametrize("style", ["runs", "rand", "mixed"])
+def _run_table(style, w, rng):
+    """``(values, stream, ends, kinds, payloads, bit_offsets)`` of one hybrid
+    stream of ``w``-bit values, its run table as the device reader stages
+    it; ``values`` is None where the caller expands past the last run."""
+    n, hi = 3777, 1 << w
+    if style in ("runs", "rand", "mixed", "zero_len", "padded"):
+        if style == "runs":
+            v = np.repeat(rng.integers(0, hi, size=50),
+                          rng.integers(1, 200, size=50))[:n]
+        elif style == "rand":
+            v = rng.integers(0, hi, size=n)
+        else:
+            v = np.where(rng.random(n) < 0.5, 1, rng.integers(0, hi, size=n))
+        enc = np.frombuffer(ref.encode_rle(v, w), np.uint8)
+        kinds, counts, payloads, offsets, _ = ref.scan_rle_runs(enc, len(v), w)
+        ends, offsets = np.cumsum(counts), offsets * 8
+        if style == "zero_len":  # empty runs of both kinds, first and last too
+            at = np.sort(rng.integers(0, len(kinds) + 1, size=12))
+            at[0], at[-1] = 0, len(kinds)
+            ends = np.insert(ends, at, np.concatenate([[0], ends])[at])
+            kinds = np.insert(kinds, at, rng.integers(0, 2, size=12))
+            payloads = np.insert(payloads, at, rng.integers(0, hi, size=12))
+            offsets = np.insert(offsets, at,
+                                rng.integers(0, 8 * len(enc), size=12))
+        if style == "padded":  # the last run reads on into zero padding
+            v, enc = None, np.concatenate([enc, np.zeros(256, np.uint8)])
+        return v, enc, ends, kinds, payloads, offsets
+    if style == "all_rle":
+        counts = rng.integers(1, 200, size=40)
+        payloads = rng.integers(0, hi, size=40)
+        zeros = np.zeros(40, np.int64)
+        return (np.repeat(payloads, counts), np.zeros(8, np.uint8),
+                np.cumsum(counts), zeros.astype(np.uint8), payloads, zeros)
+    if style == "single":  # the all-present def-level page: one RLE run
+        p = int(rng.integers(0, hi))
+        return (np.full(n, p), np.zeros(8, np.uint8), np.array([n]),
+                np.zeros(1, np.uint8), np.array([p]), np.zeros(1, np.int64))
+    v = rng.integers(0, hi, size=n)
+    enc = np.frombuffer(ref.pack_bits(v, w), np.uint8)
+    if style == "single_packed":  # a PLAIN BOOLEAN page: one bit-packed run
+        ends = np.array([n])
+    else:  # all_packed: groups of 8 values, the last run cut short
+        ends = np.cumsum(8 * rng.integers(1, 64, size=n // 8))
+        ends = np.append(ends[ends < n], n)
+    starts = np.concatenate([[0], ends[:-1]])
+    return (v, enc, ends, np.ones(len(ends), np.uint8),
+            np.zeros(len(ends), np.int64), starts * w)
+
+
+@pytest.mark.parametrize("w", [1, 3, 8, 12, 20, 31, 32])
+@pytest.mark.parametrize("style", ["runs", "rand", "mixed", "zero_len",
+                                   "padded", "all_rle", "all_packed",
+                                   "single", "single_packed"])
 def test_rle_expand(w, style, rng):
-    n = 3777
-    if style == "runs":
-        v = np.repeat(rng.integers(0, 1 << w, size=50), rng.integers(1, 200, size=50))[:n]
-    elif style == "rand":
-        v = rng.integers(0, 1 << w, size=n)
-    else:
-        v = np.where(rng.random(n) < 0.5, 1, rng.integers(0, 1 << w, size=n))
-    n = len(v)
-    enc = ref.encode_rle(v, w)
-    buf = np.frombuffer(enc, np.uint8)
-    kinds, counts, payloads, offsets, _ = ref.scan_rle_runs(buf, n, w)
-    out = device.rle_expand(
-        _pad(enc), n,
-        np.cumsum(counts).astype(np.int64), kinds,
-        payloads.astype(np.int32),
-        offsets * 8, np.full(len(kinds), w, dtype=np.int32))
-    np.testing.assert_array_equal(np.asarray(out), v)
+    """Bit for bit the host twin's expansion (and the encoded values); a
+    padded ``n`` past the last end extends the last run."""
+    v, enc, ends, kinds, payloads, offsets = _run_table(style, w, rng)
+    n = int(ends[-1]) + (37 if v is None else 0)
+    widths = np.full(len(kinds), w, dtype=np.int32)
+    out = np.asarray(device.rle_expand(
+        _pad(enc), n, ends.astype(np.int64), kinds,
+        payloads.astype(np.uint32).view(np.int32), offsets, widths))
+    if v is not None:
+        np.testing.assert_array_equal(out.view(np.uint32), v.astype(np.uint32))
+    twin_ends = np.asarray(ends, np.int64).copy()
+    twin_ends[-1] = n  # the host twin stops at the last end
+    twin = _RunTable(ends=[twin_ends], kinds=[kinds], payloads=[payloads],
+                     bit_offsets=[offsets], widths=[widths], total=n)
+    np.testing.assert_array_equal(out, twin.expand_host(enc, n))
 
 
 def test_rle_expand_mixed_widths(rng):
@@ -96,6 +146,10 @@ def test_rle_expand_mixed_widths(rng):
     widths = np.concatenate([np.full(len(k1), 4), np.full(len(k2), 9)]).astype(np.int32)
     out = device.rle_expand(_pad(buf), 2500, ends, kinds, payloads, offsets, widths)
     np.testing.assert_array_equal(np.asarray(out), np.concatenate([v1, v2]))
+    twin = _RunTable(ends=[ends], kinds=[kinds], payloads=[payloads],
+                     bit_offsets=[offsets], widths=[widths], total=2500)
+    np.testing.assert_array_equal(
+        np.asarray(out), twin.expand_host(np.frombuffer(buf, np.uint8)))
 
 
 @pytest.mark.parametrize("n", [1, 2, 33, 128, 129, 1000])

@@ -597,11 +597,43 @@ def _kernel_bytes_from_metadata(raw: bytes) -> dict:
             "kernel_bytes.fixed64_pairs": pairs}
 
 
-def test_kernel_byte_counters_match_the_file_metadata(monkeypatch, rng):
-    """``kernel_bytes.*`` count the work the data fixes: two reads of one
-    file count the same bytes, and those are the page headers' formula."""
-    from parquet_tpu import counters
+def _kernel_runs_from_pages(raw: bytes) -> int:
+    """The runs ``rle_expand`` is handed for one device read, scanned from
+    the file's pages: per flat nullable column with nulls, the runs of its
+    def-level streams; per dictionary column, the runs of its index
+    streams (past the bit-width byte)."""
+    from parquet_tpu.format.enums import Encoding, PageType
+    from parquet_tpu.ops import ref
 
+    pf = ParquetFile(raw)
+    runs = 0
+    for g in range(len(pf.row_groups)):
+        for leaf in pf.schema.leaves:
+            reader = pf.row_group(g).column(leaf.column_index)
+            pages = [p for p in reader.pages()
+                     if p.page_type == PageType.DATA_PAGE_V2]
+            nulls = sum(p.header.data_page_header_v2.num_nulls for p in pages)
+            for p in pages:
+                d = p.header.data_page_header_v2
+                dl = d.definition_levels_byte_length
+                if nulls:
+                    levels = np.frombuffer(p.payload[:dl], np.uint8)
+                    runs += len(ref.scan_rle_runs(levels, d.num_values, 1)[0])
+                if Encoding(d.encoding) == Encoding.RLE_DICTIONARY:
+                    body = p.payload[dl:]
+                    if d.is_compressed is not False:
+                        body = reader.codec.decode(
+                            body, p.header.uncompressed_page_size - dl)
+                    body = np.frombuffer(body, np.uint8)
+                    runs += len(ref.scan_rle_runs(
+                        body[1:], d.num_values - d.num_nulls, int(body[0]))[0])
+    return runs
+
+
+def _kernel_counter_file(monkeypatch, rng):
+    """A file whose device read runs both kernels, with the device routes
+    pinned: a PLAIN int64, a nullable double, a nullable dictionary int32,
+    several V2 pages per chunk."""
     for knob in ("PARQUET_TPU_PLAIN_RUNS", "PARQUET_TPU_DICT_RUNS"):
         monkeypatch.setenv(knob, "device")
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "off")  # indices via the runs
@@ -614,6 +646,41 @@ def test_kernel_byte_counters_match_the_file_metadata(monkeypatch, rng):
     })
     raw = _write(t, use_dictionary=["d32n"], data_page_version="2.0",
                  row_group_size=2500, data_page_size=4096)
+    return raw, t
+
+
+def test_kernel_run_counter_matches_the_page_streams(monkeypatch, rng):
+    """``kernel_runs.rle_expand`` is the run-table length summed over a
+    read: what the pages' hybrid streams scan to, and what the kernel was
+    handed, the same on every read of one file."""
+    from parquet_tpu import counters
+    from parquet_tpu.ops import device as dev
+
+    raw, t = _kernel_counter_file(monkeypatch, rng)
+    want = _kernel_runs_from_pages(raw)
+    handed = []
+    expand = dev.rle_expand
+
+    def spy(buf, n, run_ends, *rest):
+        handed.append(int(run_ends.shape[0]))
+        return expand(buf, n, run_ends, *rest)
+
+    monkeypatch.setattr(dev, "rle_expand", spy)
+    assert want
+    for _ in range(2):
+        handed.clear()
+        before = counters.snapshot().get("kernel_runs.rle_expand", 0)
+        _check(raw, t)
+        got = counters.snapshot().get("kernel_runs.rle_expand", 0) - before
+        assert got == want == sum(handed)
+
+
+def test_kernel_byte_counters_match_the_file_metadata(monkeypatch, rng):
+    """``kernel_bytes.*`` count the work the data fixes: two reads of one
+    file count the same bytes, and those are the page headers' formula."""
+    from parquet_tpu import counters
+
+    raw, t = _kernel_counter_file(monkeypatch, rng)
     want = _kernel_bytes_from_metadata(raw)
     assert all(want.values())
     for _ in range(2):
