@@ -6,7 +6,8 @@ bytestreamsplit asm) at the same insertion point — the ``encoding.Encoding``
 registry.  Design per SURVEY.md §7:
 
 - All kernels are pure functions of flat uint8 buffers + small metadata
-  arrays, jit-compiled with static shapes (bucket-padded by the caller).
+  arrays, jit-compiled with static shapes (bucket-padded by the caller);
+  PLAIN fixed-width values come as exact-length uint32 words instead.
 - The inherently sequential work (run-header varint scans, miniblock header
   walks) happens on host at *metadata* scale (bytes per run/miniblock), then
   the device does the wide expansion at *data* scale — the two-pass split of
@@ -69,8 +70,13 @@ MAX_DEVICE_BUF = 1 << 27
 def _as_words(buf: jax.Array) -> jax.Array:
     """uint8 staged buffer → uint32 little-endian word view (zero-padded to a
     word boundary; out-of-range word gathers are clamped by XLA and the
-    garbage bits always fall outside the value mask)."""
-    return bitcast_rows(buf, 4, _U32)
+    garbage bits always fall outside the value mask).  Callers slice the
+    words they need AFTER the bitcast: the TPU compiler takes minutes over
+    a reshape+bitcast of a buffer sliced to an arbitrary length (1M 8-byte
+    values: 319 s) and a second over the power-of-two staging bucket."""
+    if buf.shape[0] % 4:
+        buf = jnp.pad(buf, (0, 4 - buf.shape[0] % 4))
+    return jax.lax.bitcast_convert_type(buf.reshape(-1, 4), _U32)
 
 
 #: row length of :func:`cumsum`'s blocked scan
@@ -102,33 +108,37 @@ def _word_at(bit_starts: jax.Array):
 
 # ---------------------------------------------------------------------------
 # PLAIN fixed-width (the config[0] minimum slice: decode == reinterpret)
+#
+# Staged as exact-length uint32 words (``device_reader.stage_plan``): the
+# host holds PLAIN values as whole 4-byte words, so the device does no
+# byte work and touches only the chunk's n values.
 # ---------------------------------------------------------------------------
 
 
-def bitcast_rows(buf: jax.Array, row_bytes: int, dtype) -> jax.Array:
-    """The whole uint8 staged buffer as rows of ``row_bytes // size(dtype)``
-    elements of ``dtype``, zero-padded to whole rows.  Callers slice the
-    rows they need AFTER the bitcast: the TPU compiler takes minutes over a
-    reshape+bitcast of a buffer sliced to an arbitrary length (1M 8-byte
-    values: 319 s) and a second over the power-of-two staging bucket."""
-    if buf.shape[0] % row_bytes:
-        buf = jnp.pad(buf, (0, row_bytes - buf.shape[0] % row_bytes))
-    k = row_bytes // jnp.dtype(dtype).itemsize
-    rows = buf.reshape(-1, k, row_bytes // k) if k > 1 else \
-        buf.reshape(-1, row_bytes)
-    return jax.lax.bitcast_convert_type(rows, dtype)
-
-
 @partial(jax.jit, static_argnames=("n", "dtype"))
-def bitcast_fixed32(buf: jax.Array, n: int, dtype: str) -> jax.Array:
-    """uint8 → {int32,uint32,float32}[n] (PLAIN 4-byte types)."""
-    return bitcast_rows(buf, 4, jnp.dtype(dtype))[:n]
+def bitcast_fixed32(words: jax.Array, n: int, dtype: str) -> jax.Array:
+    """uint32 words → {int32,uint32,float32}[n] (PLAIN 4-byte types): a
+    same-width bitcast, no byte work."""
+    return jax.lax.bitcast_convert_type(words[:n], jnp.dtype(dtype))
 
 
 @partial(jax.jit, static_argnames=("n",))
-def fixed64_pairs(buf: jax.Array, n: int) -> jax.Array:
-    """uint8 → uint32[n,2] lo/hi pairs (PLAIN 8-byte types, byte-exact)."""
-    return bitcast_rows(buf, 8, _U32)[:n]
+def fixed64_pairs(words: jax.Array, n: int) -> jax.Array:
+    """uint32 words → uint32[n,2] lo/hi pairs (PLAIN 8-byte types,
+    byte-exact), no byte work.  The result is a new buffer (the input is
+    neither donated nor returned), so every call runs a
+    ``jit_fixed64_pairs`` program.
+
+    A value's lo and hi words sit in neighbouring lanes, and the TPU lays
+    ``u32[n,2]`` out as a lo row and a hi row per 128 values: so the words
+    go as rows of 256, split by strided lane slices into ``[rows, 2, 128]``
+    blocks, which are that layout.  A plain ``reshape(n, 2)`` goes through
+    a 64x lane-padded intermediate instead (for a described v5e at 1M
+    values: 14x the estimated cycles and 512 MiB of scratch)."""
+    m = -(-n // 128) * 128
+    rows = jnp.pad(words[: 2 * n], (0, 2 * (m - n))).reshape(-1, 256)
+    blocks = jnp.stack([rows[:, 0::2], rows[:, 1::2]], axis=1)
+    return blocks.transpose(0, 2, 1).reshape(m, 2)[:n]
 
 
 @partial(jax.jit, static_argnames=("n",))

@@ -316,6 +316,16 @@ class _ByteAccum:
             pos += len(p)
         return out
 
+    def words(self) -> np.ndarray:
+        """The stream as exactly ``len(self) // 4`` little-endian uint32
+        words, no padding: a zero-copy view of one word-aligned part, else
+        one copy into an exact-size buffer.  For PLAIN fixed-width values,
+        whose byte count is a whole number of words."""
+        a = self.array()
+        if a.ctypes.data % 4:
+            a = a.copy()
+        return a.view(np.uint32)
+
     def tobytes(self) -> bytes:
         return self.array().tobytes()
 
@@ -746,6 +756,10 @@ def _stage_values(plan: _Plan, raw: np.ndarray, pos: int, nvals: int,
         if physical in _FIXED_WIDTH:
             plan.set_kind("plain_fixed")
             w = _FIXED_WIDTH[physical]
+            if len(raw) - pos < nvals * w:
+                # staged as exact words: a short page has no device form
+                # (the host decode reports the corruption)
+                raise _Unsupported("PLAIN page shorter than its values")
             plan.values.extend(raw[pos : pos + nvals * w])
             plan.plain_total += nvals
             return
@@ -1139,8 +1153,12 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
             not plain_host and not delta_host and not bss_host and \
             plan.value_kind not in (None, "host_ba"):
         # staged even when empty (all-null chunks have no value bytes): the
-        # kernels need a real buffer operand to slice [:0] from
-        val_dbuf = put(plan.values.padded_array())
+        # kernels need a real buffer operand to slice [:0] from.  PLAIN
+        # fixed-width values go as exact-length uint32 words (their kernels
+        # only reshape and bitcast words); the other streams keep the
+        # padded uint8 bucket their bit-offset gathers read past the end of
+        val_dbuf = put(plan.values.words() if plan.value_kind == "plain_fixed"
+                       else plan.values.padded_array())
         counters.inc("bytes_h2d", len(plan.values))
     if dense_route:
         # compacted single-width index stream replaces the raw bodies
@@ -1662,9 +1680,10 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
             counters.inc("kernel_bytes.fixed64_pairs", 16 * nvals)
             values = dev.fixed64_pairs(val_dbuf, nvals)
         elif physical == Type.INT96:
-            values = dev.bitcast_rows(val_dbuf, 12, jnp.uint32)[:nvals]
+            values = val_dbuf.reshape(nvals, 3)
         else:
             dt = {Type.INT32: "int32", Type.FLOAT: "float32"}[physical]
+            counters.inc("kernel_bytes.bitcast_fixed32", 8 * nvals)
             values = dev.bitcast_fixed32(val_dbuf, nvals, dt)
     elif kind == "plain_flba":
         if staged_meta.get("plain_host"):
@@ -1901,7 +1920,7 @@ def _dense_unpack_pages(dense_buf, nbytes: int, total: int, w: int,
     # round word count UP: the stream's byte length need not be 4-aligned and
     # pad_to_bucket(extra=4) guarantees ≥4 zero bytes of slack past the end
     nwords = (nbytes + 3) // 4
-    words = dev.bitcast_rows(dense_buf, 4, jnp.uint32)[:nwords]
+    words = dev._as_words(dense_buf)[:nwords]
     if pallas:
         allidx = pk.unpack_bits_dense(words, total, w, interpret=interpret)
     else:

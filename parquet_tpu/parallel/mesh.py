@@ -571,9 +571,10 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
     counts its kernels' logical bytes (``kernel_bytes.*`` counters) and
     the runs its ``rle_expand`` scatters (``kernel_runs.rle_expand``).
 
-    Input: per-device staging buffers ``bytes_in [n_dev, B]`` (uint8, each
-    device's batch of PLAIN INT64 page bytes), level buffers and run tables
-    likewise stacked on the leading mesh axis.  Each device decodes its shard
+    Input: per-device value words ``words_in [n_dev, W]`` (uint32, each
+    device's batch of PLAIN INT64 values as little-endian words, ``W >=
+    2 * n_per_shard``), uint8 level buffers and run tables likewise stacked
+    on the leading mesh axis.  Each device decodes its shard
     (bitcast + RLE def-level expand + validity + null scatter); a psum'd
     row-count rides the ICI as the collective (the "global row count" a
     distributed scan wants).  This is the full per-step compute of the decode
@@ -582,11 +583,12 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
     spec = P(axis)
     rep = P()
 
-    def step(vbuf, lbuf, run_ends, run_kinds, run_payloads, run_offs, run_widths):
+    def step(vwords, lbuf, run_ends, run_kinds, run_payloads, run_offs,
+             run_widths):
         # one device's shard: drop the leading axis of size 1
-        vb = vbuf.reshape(vbuf.shape[-1])
+        vw = vwords.reshape(vwords.shape[-1])
         lb = lbuf.reshape(lbuf.shape[-1])
-        pairs = dev.fixed64_pairs(vb, n_per_shard)
+        pairs = dev.fixed64_pairs(vw, n_per_shard)
         defs = dev.rle_expand(lb, n_per_shard, run_ends.reshape(-1),
                               run_kinds.reshape(-1), run_payloads.reshape(-1),
                               run_offs.reshape(-1), run_widths.reshape(-1))
@@ -603,7 +605,7 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
         check_vma=False))
     n_dev = mesh.shape[axis]
 
-    def call(vbuf, lbuf, *runs):
+    def call(vwords, lbuf, *runs):
         # the kernels' logical bytes, counted per call here: inside the
         # traced step they would count once per trace.  The step is handed
         # no unpadded level-stream length, so its encoded level bytes are
@@ -612,7 +614,7 @@ def decode_step_sharded(mesh: Mesh, n_per_shard: int, axis: str = "data"):
         counters.inc("kernel_bytes.rle_expand",
                      int(np.prod(lbuf.shape)) + 4 * n_per_shard * n_dev)
         counters.inc("kernel_runs.rle_expand", int(np.prod(runs[0].shape)))
-        return sharded(vbuf, lbuf, *runs)
+        return sharded(vwords, lbuf, *runs)
 
     return call
 

@@ -17,19 +17,69 @@ def _pad(b) -> np.ndarray:
     return device.pad_to_bucket(np.frombuffer(b, np.uint8) if isinstance(b, bytes) else b)
 
 
-def test_bitcast_fixed32(rng):
-    for dtype in ["int32", "float32", "uint32"]:
-        v = rng.integers(0, 1000, size=777).astype(dtype)
-        out = device.bitcast_fixed32(_pad(v.tobytes()), 777, dtype)
-        np.testing.assert_array_equal(np.asarray(out), v)
+#: value counts of the PLAIN kernels: empty (an all-null chunk), one, an
+#: odd count, a full 2^20-row group and lineitem SF1's last row group
+PLAIN_NS = [0, 1, 777, 1 << 20, 758_335]
 
 
-def test_fixed64_pairs(rng):
-    for dtype in ["int64", "float64"]:
-        v = (rng.integers(-(2**62), 2**62, size=777).astype(dtype)
-             if dtype == "int64" else rng.random(777))
-        out = device.fixed64_pairs(_pad(v.tobytes()), 777)
-        np.testing.assert_array_equal(device.pairs_to_host(out, dtype), v)
+def _plain_values(dtype, n, rng) -> np.ndarray:
+    """``n`` values of ``dtype`` from random bits; floats carry NaN payloads
+    (quiet, signalling, signed) and -0.0, which must survive bit for bit."""
+    bits = {4: np.uint32, 8: np.uint64}[np.dtype(dtype).itemsize]
+    v = rng.integers(0, np.iinfo(bits).max, size=n, dtype=bits,
+                     endpoint=True)
+    if np.dtype(dtype).kind == "f":
+        special = ([0x7FF8000000000001, 0x7FF0000000000001,
+                    0xFFF0000000000123, 0x8000000000000000]
+                   if bits is np.uint64 else
+                   [0x7FC00001, 0x7F800001, 0xFF800123, 0x80000000])
+        k = min(n, len(special))
+        v[:k] = np.array(special[:k], bits)
+    return v.view(dtype)
+
+
+@pytest.mark.parametrize("n", PLAIN_NS)
+@pytest.mark.parametrize("dtype", ["int32", "float32", "uint32"])
+def test_bitcast_fixed32(dtype, n, rng):
+    """PLAIN 4-byte values from their staged words: a bitcast, bit for bit
+    the numpy view of the same bytes."""
+    v = _plain_values(dtype, n, rng)
+    out = np.asarray(device.bitcast_fixed32(v.view(np.uint32), n, dtype))
+    assert out.dtype == np.dtype(dtype) and out.shape == (n,)
+    np.testing.assert_array_equal(out.view(np.uint32), v.view(np.uint32))
+
+
+@pytest.mark.parametrize("n", PLAIN_NS)
+@pytest.mark.parametrize("dtype", ["int64", "float64"])
+def test_fixed64_pairs(dtype, n, rng):
+    """PLAIN 8-byte values from their staged words: ``(n, 2)`` lo/hi pairs,
+    bit for bit the numpy view of the same bytes."""
+    v = _plain_values(dtype, n, rng)
+    words = v.view(np.uint32)
+    out = device.fixed64_pairs(words, n)
+    assert out.shape == (n, 2) and out.dtype == np.uint32
+    np.testing.assert_array_equal(np.asarray(out), words.reshape(n, 2))
+    np.testing.assert_array_equal(
+        device.pairs_to_host(out, dtype).view(np.uint64), v.view(np.uint64))
+
+
+def test_fixed64_pairs_runs_a_program():
+    """``fixed64_pairs`` hands back a new buffer made by a program of its
+    own: returning (or donating) its input would let JAX forward the call
+    without running ``jit_fixed64_pairs``, and the kernel's roofline would
+    read nothing."""
+    import jax
+
+    n = 777
+    words = jax.numpy.asarray(np.arange(2 * n, dtype=np.uint32))
+    out = device.fixed64_pairs(words, n)
+    assert out.unsafe_buffer_pointer() != words.unsafe_buffer_pointer()
+    words.delete()  # the result holds none of the input
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.arange(2 * n).reshape(n, 2))
+    inner = jax.make_jaxpr(lambda w: device.fixed64_pairs(w, n))(
+        jax.ShapeDtypeStruct((2 * n,), np.uint32)).eqns[0].params["jaxpr"]
+    assert inner.eqns and inner.jaxpr.outvars[0] not in inner.jaxpr.invars
 
 
 def test_unpack_bools(rng):

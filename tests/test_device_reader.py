@@ -689,3 +689,104 @@ def test_kernel_byte_counters_match_the_file_metadata(monkeypatch, rng):
         after = counters.snapshot()
         got = {k: after.get(k, 0) - before.get(k, 0) for k in want}
         assert got == want
+
+
+#: PLAIN fixed-width columns by physical type: (arrow type, bytes a value)
+_PLAIN_FIXED = {"INT64": (pa.int64(), 8), "DOUBLE": (pa.float64(), 8),
+                "INT32": (pa.int32(), 4), "FLOAT": (pa.float32(), 4),
+                "INT96": (pa.timestamp("ns"), 12)}
+
+
+def _plain_fixed_file(physical, rng, shape):
+    """One PLAIN column of ``physical``: several pages (``multipage``), a
+    share of nulls (``nulls``) or nothing but nulls (``all_null``)."""
+    typ, _ = _PLAIN_FIXED[physical]
+    n = 9000
+    v = rng.integers(-(2**30), 2**30, n)
+    if physical in ("DOUBLE", "FLOAT"):
+        v = rng.standard_normal(n)
+    mask = {"multipage": None, "nulls": rng.random(n) < 0.3,
+            "all_null": np.ones(n, bool)}[shape]
+    t = pa.table({"x": pa.array(v, mask=mask).cast(typ)})
+    raw = _write(t, use_dictionary=False, data_page_size=4096,
+                 use_deprecated_int96_timestamps=physical == "INT96")
+    return raw, t
+
+
+@pytest.mark.parametrize("shape", ["multipage", "nulls", "all_null"])
+@pytest.mark.parametrize("physical", list(_PLAIN_FIXED))
+def test_plain_fixed_stages_exact_words(physical, shape, monkeypatch, rng):
+    """A PLAIN fixed-width chunk on the device route stages its values as
+    one uint32 array of exactly ``nvals * width / 4`` words (no padded
+    bucket), ``bytes_h2d`` grows by exactly those bytes, and the read
+    counts ``16 n`` for ``fixed64_pairs`` and ``8 n`` for
+    ``bitcast_fixed32``."""
+    from parquet_tpu import counters
+    from parquet_tpu.format.enums import Type
+    from parquet_tpu.parallel import device_reader as dr
+
+    monkeypatch.setenv("PARQUET_TPU_PLAIN_RUNS", "device")
+    raw, t = _plain_fixed_file(physical, rng, shape)
+    chunk = ParquetFile(raw).row_group(0).column(0)
+    assert Type(chunk.meta.type) == Type[physical]
+    assert shape != "multipage" or len(list(chunk.pages())) > 2
+    plan = dr.build_plan(chunk)
+    width = _PLAIN_FIXED[physical][1]
+    nvals = len(t) - t["x"].null_count
+    assert plan.total_values == nvals
+    put = []
+    before = counters.snapshot().get("bytes_h2d", 0)
+    _, words, _ = dr.stage_plan(plan, stage_levels=False,
+                                put=lambda a: put.append(a) or a)
+    assert counters.snapshot().get("bytes_h2d", 0) - before \
+        == words.nbytes == nvals * width
+    assert any(a is words for a in put)
+    assert words.dtype == np.uint32 and words.shape == (nvals * width // 4,)
+
+    want = {"kernel_bytes.fixed64_pairs": 16 * nvals * (width == 8),
+            "kernel_bytes.bitcast_fixed32": 8 * nvals * (width == 4)}
+    before = counters.snapshot()
+    _check(raw, t)
+    after = counters.snapshot()
+    assert {k: after.get(k, 0) - before.get(k, 0) for k in want} == want
+
+
+@pytest.mark.parametrize("parts", ["one", "misaligned", "several", "none"])
+def test_byte_accum_words_exact(parts):
+    """``_ByteAccum.words`` gives exactly ``len // 4`` little-endian words:
+    a zero-copy view of one word-aligned part, one copy otherwise."""
+    from parquet_tpu.parallel.device_reader import _ByteAccum
+
+    v = np.arange(24, dtype=np.uint32) * np.uint32(0x01010101)
+    body = v.view(np.uint8)
+    acc = _ByteAccum()
+    if parts == "one":
+        acc.extend(body)
+    elif parts == "misaligned":
+        acc.extend(np.concatenate([np.zeros(1, np.uint8), body])[1:])
+    elif parts == "several":
+        for i in range(0, 96, 28):
+            acc.extend(body[i:i + 28])
+    words = acc.words()
+    want = v if parts != "none" else v[:0]
+    assert words.dtype == np.uint32
+    np.testing.assert_array_equal(words, want)
+    assert np.shares_memory(words, body) == (parts == "one")
+
+
+@pytest.mark.parametrize("physical", list(_PLAIN_FIXED))
+def test_short_plain_page_has_no_device_form(physical):
+    """A PLAIN page shorter than its values (a corrupt file) is refused for
+    the device: its values stage as exact words, and a zero-padded bucket
+    would have decoded the missing bytes as zeros."""
+    from parquet_tpu.format.enums import Encoding, Type
+    from parquet_tpu.parallel import device_reader as dr
+
+    width = _PLAIN_FIXED[physical][1]
+    plan = dr._Plan()
+    dr._stage_values(plan, np.zeros(3 * width, np.uint8), 0, 3,
+                     Encoding.PLAIN, Type[physical], None)
+    assert len(plan.values) == 3 * width
+    with pytest.raises(dr._Unsupported, match="shorter"):
+        dr._stage_values(plan, np.zeros(3 * width - 1, np.uint8), 0, 3,
+                         Encoding.PLAIN, Type[physical], None)

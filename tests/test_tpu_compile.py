@@ -133,16 +133,22 @@ def test_dense_unpack_pages_compiles(pallas, one_chip):
     assert ("tpu_custom_call" in hlo) is pallas
 
 
+@pytest.mark.parametrize("n", [ROWS, 758_335])
 @pytest.mark.parametrize("width", [4, 8])
-def test_plain_fixed_width_compiles(width, one_chip):
-    """PLAIN int32/int64/double chunk decode at a row group's 1M values."""
+def test_plain_fixed_width_compiles(width, n, one_chip):
+    """PLAIN int32/int64/double chunk decode from the exact-length uint32
+    words the device reader stages, at a row group's 1M values and at
+    lineitem SF1's last row group: no bucket, and no compile blow-up at
+    an arbitrary length (u8 bitcasts of sliced buffers took minutes)."""
     from parquet_tpu.ops import device as dev
 
-    buf = staged(ROWS * width, one_chip)
+    words = jax.ShapeDtypeStruct((n * width // 4,), jnp.uint32,
+                                 sharding=one_chip)
     if width == 8:
-        compile_for(lambda b: dev.fixed64_pairs(b, ROWS), buf)
+        hlo = compile_for(lambda w: dev.fixed64_pairs(w, n), words)
     else:
-        compile_for(lambda b: dev.bitcast_fixed32(b, ROWS, "int32"), buf)
+        hlo = compile_for(lambda w: dev.bitcast_fixed32(w, n, "int32"), words)
+    assert "u8[" not in hlo  # no byte view of the values
 
 
 def test_rle_expand_compiles_fast(one_chip):
