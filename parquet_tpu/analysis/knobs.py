@@ -213,24 +213,10 @@ declare("PARQUET_TPU_PALLAS", "str", "",
         "dense bit-unpack routing: 1/pallas = the Pallas kernel (interpret "
         "mode off the TPU), 0/jnp = the jnp twin, off = per-value gathers; "
         "unset = the kernel on a TPU, the twin elsewhere")
-declare("PARQUET_TPU_PLAIN_RUNS", "str", "",
-        "pin PLAIN fixed-width chunk decode: host|device; unset routes "
-        "per backend")
-declare("PARQUET_TPU_DICT_RUNS", "str", "",
-        "pin mixed-run dictionary index decode: host|device")
-declare("PARQUET_TPU_DELTA_RUNS", "str", "",
-        "pin DELTA_BINARY_PACKED decode: host|device")
-declare("PARQUET_TPU_BSS_RUNS", "str", "",
-        "pin BYTE_STREAM_SPLIT decode: host|device")
-declare("PARQUET_TPU_DBA_RUNS", "str", "",
-        "pin DELTA_BYTE_ARRAY decode: host|device")
 declare("PARQUET_TPU_DEVICE_OVERLAP", "str", "auto",
         "mesh-read stage/decode pipelining: 0/off=stage then decode "
         "sequentially, auto=overlap when the shard has >1 file, "
         "force=always submit stage N+1 before decode N")
-declare("PARQUET_TPU_DEVICE_ASM", "str", "",
-        "nested-column device assembly: 1 forces device, 0 forces host; "
-        "unset routes per backend")
 declare("PARQUET_TPU_NO_X64", "bool", False,
         "skip enabling jax 64-bit mode at import (INT64/FP64 columns "
         "then decode via the 32-bit paths)")

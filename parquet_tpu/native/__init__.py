@@ -164,11 +164,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
         lib.pq_scan_rle_runs.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
             _u8p_w, _i64p, _i64p, _i64p]
-        lib.pq_expand_gather.restype = ctypes.c_int64
-        lib.pq_expand_gather.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, _i64p, ctypes.c_void_p, _i64p,
-            _i64p, _i32p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32]
         lib.pq_delta_decode.restype = ctypes.c_int64
         lib.pq_delta_decode.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, _i64p, _i32p, _i64p, _i64p,
@@ -186,13 +181,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
         lib.pq_count_target_in_runs.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, _i64p, _i64p,
             _i64p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int64]
-        lib.pq_dict_chunk_scan.restype = ctypes.c_int64
-        lib.pq_dict_chunk_scan.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, _i64p, ctypes.c_int64,
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-            _u8p_w, ctypes.c_int64,
-            _i64p_w, _u8p_w, _i64p_w, _i64p_w, _i32p_w, ctypes.c_int64,
-            _i64p_w, ctypes.c_int32]
         lib.pq_decompress_pages.restype = ctypes.c_int64
         lib.pq_decompress_pages.argtypes = [
             _i64p, _i64p, ctypes.c_int64, ctypes.c_int32, _u8p_w, _i64p,
@@ -655,40 +643,6 @@ def delta_decode(buf: np.ndarray, mb_bitoffs, mb_widths, mb_mins,
     return out
 
 
-def expand_gather(buf: np.ndarray, tables: tuple, n: int,
-                  dictionary: np.ndarray, nthreads: int = 0):
-    """Fused RLE/bit-packed index expand + dictionary gather: run tables →
-    gathered values in one multithreaded native pass (no index stream).
-    ``tables`` = (ends, kinds, payloads, bit_offsets, widths) in the int64
-    host domain.  Returns the gathered array or None (unavailable shape →
-    caller uses expand + numpy gather)."""
-    lib = get_lib()
-    if lib is None or n == 0:
-        return None
-    elem = dictionary.dtype.itemsize
-    if elem not in (4, 8) or dictionary.ndim != 1:
-        return None
-    ends, kinds, payloads, offs, widths32 = tables
-    buf = np.ascontiguousarray(buf)
-    dvals = np.ascontiguousarray(dictionary)
-    out = np.empty(n, dtype=dictionary.dtype)
-    if not nthreads:
-        nthreads = _auto_threads()
-    rc = lib.pq_expand_gather(
-        buf.ctypes.data if len(buf) else None, len(buf),
-        np.ascontiguousarray(ends, np.int64),
-        np.ascontiguousarray(kinds, np.uint8).ctypes.data,
-        np.ascontiguousarray(payloads, np.int64),
-        np.ascontiguousarray(offs, np.int64),
-        np.ascontiguousarray(widths32, np.int32), len(ends), n,
-        dvals.ctypes.data, len(dvals), elem,
-        out.ctypes.data, nthreads)
-    if rc != 0:
-        raise ValueError("malformed dictionary run stream "
-                         "(index out of range or bad width)")
-    return out
-
-
 # column indexes of a pq_scan_page_headers row — keep in sync with the
 # PG_* enum in native.cpp
 PG_HEADER_POS = 0
@@ -776,49 +730,6 @@ def count_target_in_runs(body: np.ndarray, kinds, cnts, payloads, offs,
         np.ascontiguousarray(payloads, np.int64),
         np.ascontiguousarray(offs, np.int64), len(kinds), width, target)
     return None if n < 0 else int(n)
-
-
-def dict_chunk_scan(buf, pages_rows: np.ndarray, codec_id: int,
-                    max_def: int, max_rep: int):
-    """Fused whole-chunk dictionary-index scan: decompress every data page
-    (UNCOMPRESSED/SNAPPY/ZSTD), verify all-present def levels, and scan the
-    index runs into one combined chunk-level run table in a single native
-    call (the per-page Python loop was ~60% of build_plan's host time at
-    64 MB / 400 pages).
-
-    Returns ``(ends, kinds, payloads, bit_offsets, widths, nvals, body)``
-    with offsets indexing ``body`` (the concatenated decompressed pages), or
-    None when the chunk needs the general Python planner (nulls, rep levels,
-    non-dict pages, foreign codec, no native lib)."""
-    lib = get_lib()
-    if lib is None:
-        return None
-    b = buf if isinstance(buf, np.ndarray) else np.frombuffer(buf, np.uint8)
-    b = np.ascontiguousarray(b)
-    rows = np.ascontiguousarray(pages_rows, np.int64)
-    n_pages = len(rows)
-    data = rows[(rows[:, PG_TYPE] == 0) | (rows[:, PG_TYPE] == 3)]
-    if not len(data):
-        return None
-    out_cap = int(data[:, PG_UNCOMP].sum()) + 8
-    nvals_cap = int(data[:, PG_NVALS].sum())
-    run_cap = nvals_cap + n_pages + 8
-    out_bytes = np.empty(out_cap, np.uint8)
-    ends = np.empty(run_cap, np.int64)
-    kinds = np.empty(run_cap, np.uint8)
-    payloads = np.empty(run_cap, np.int64)
-    boffs = np.empty(run_cap, np.int64)
-    widths = np.empty(run_cap, np.int32)
-    info = np.zeros(2, np.int64)
-    k = lib.pq_dict_chunk_scan(
-        b.ctypes.data if len(b) else None, len(b), rows.reshape(-1),
-        n_pages, codec_id, max_def, max_rep,
-        out_bytes, out_cap, ends, kinds, payloads, boffs, widths, run_cap,
-        info, _auto_threads())
-    if k < 0:
-        return None
-    return (ends[:k], kinds[:k], payloads[:k], boffs[:k] * 8, widths[:k],
-            int(info[0]), out_bytes[: info[1]])
 
 
 def scan_rle_runs(buf: np.ndarray, n: int, bit_width: int):

@@ -945,115 +945,6 @@ int64_t pq_delta_decode(const uint8_t* buf, int64_t buf_len,
 }  // extern "C" (the helpers below use templates — C++ linkage)
 
 // ---------------------------------------------------------------------------
-// Fused RLE/bit-packed expand + dictionary gather (multithreaded).
-// The host route for mixed-run dictionary chunks (BASELINE config 2): one
-// pass from the run table straight to gathered values — no materialized
-// index stream, output-partitioned across threads at run boundaries.
-// ---------------------------------------------------------------------------
-
-namespace {
-
-template <int ELEM>
-bool expand_gather_span(const uint8_t* buf, int64_t buf_len,
-                        const int64_t* ends, const uint8_t* kinds,
-                        const int64_t* payloads, const int64_t* bit_offsets,
-                        const int32_t* widths, int64_t nruns,
-                        const uint8_t* dict, int64_t dict_n,
-                        int64_t lo, int64_t hi, uint8_t* out) {
-  // first run containing value index `lo` (ends are cumulative counts)
-  int64_t r = std::upper_bound(ends, ends + nruns, lo) - ends;
-  int64_t v = lo;
-  while (v < hi && r < nruns) {
-    const int64_t run_start = r ? ends[r - 1] : 0;
-    const int64_t run_end = ends[r] < hi ? ends[r] : hi;
-    if (kinds[r] == 0) {  // RLE: one dictionary value fills the span
-      const int64_t idx = payloads[r];
-      if (idx < 0 || idx >= dict_n) return false;
-      const uint8_t* src = dict + idx * ELEM;
-      for (int64_t j = v; j < run_end; ++j)
-        std::memcpy(out + j * ELEM, src, ELEM);
-    } else {  // bit-packed span: unpack the index inline, gather
-      const int32_t w = widths[r];
-      if (w < 0 || w > 32) return false;
-      const uint64_t mask = (w >= 32) ? 0xFFFFFFFFull : ((1ull << w) - 1);
-      int64_t bit = bit_offsets[r] + (v - run_start) * (int64_t)w;
-      if (w <= 28) {
-        const int kper = w ? 57 / w : 1;
-        // every representable index is in range when the width's mask is
-        // below the dictionary size — hoist the per-value bounds check
-        const bool safe = (int64_t)mask < dict_n;
-        int64_t j = v;
-        while (j < run_end) {
-          uint64_t word = load8_clamped(buf, buf_len, bit >> 3) >> (bit & 7);
-          int m = (int)((run_end - j < kper) ? (run_end - j) : kper);
-          if (safe) {
-            for (int t = 0; t < m; ++t)
-              std::memcpy(out + (j + t) * ELEM,
-                          dict + ((word >> (t * w)) & mask) * ELEM, ELEM);
-          } else {
-            for (int t = 0; t < m; ++t) {
-              const int64_t idx = (int64_t)((word >> (t * w)) & mask);
-              if (idx >= dict_n) return false;
-              std::memcpy(out + (j + t) * ELEM, dict + idx * ELEM, ELEM);
-            }
-          }
-          j += m;
-          bit += (int64_t)m * w;
-        }
-      } else {
-        for (int64_t j = v; j < run_end; ++j) {
-          uint64_t word = load8_clamped(buf, buf_len, bit >> 3);
-          const int64_t idx = (int64_t)((word >> (bit & 7)) & mask);
-          if (idx >= dict_n) return false;
-          std::memcpy(out + j * ELEM, dict + idx * ELEM, ELEM);
-          bit += w;
-        }
-      }
-    }
-    v = run_end;
-    if (v >= ends[r]) ++r;
-  }
-  return v >= hi;
-}
-
-}  // namespace
-
-extern "C" int64_t pq_expand_gather(
-    const uint8_t* buf, int64_t buf_len, const int64_t* ends,
-    const uint8_t* kinds, const int64_t* payloads, const int64_t* bit_offsets,
-    const int32_t* widths, int64_t nruns, int64_t n, const uint8_t* dict,
-    int64_t dict_n, int32_t elem, uint8_t* out, int32_t nthreads) {
-  if (n <= 0) return 0;
-  if (elem != 4 && elem != 8) return -1;
-  auto span = [&](int64_t lo, int64_t hi) -> bool {
-    return elem == 4
-               ? expand_gather_span<4>(buf, buf_len, ends, kinds, payloads,
-                                       bit_offsets, widths, nruns, dict,
-                                       dict_n, lo, hi, out)
-               : expand_gather_span<8>(buf, buf_len, ends, kinds, payloads,
-                                       bit_offsets, widths, nruns, dict,
-                                       dict_n, lo, hi, out);
-  };
-  int T = nthreads;
-  if (T < 1) T = 1;
-  if (T > 16) T = 16;
-  if ((int64_t)T > n / 65536) T = (int)(n / 65536) ? (int)(n / 65536) : 1;
-  if (T == 1) return span(0, n) ? 0 : -1;
-  std::vector<std::thread> threads;
-  std::vector<char> ok((size_t)T, 1);
-  const int64_t per = (n + T - 1) / T;
-  for (int t = 1; t < T; ++t) {
-    const int64_t lo = per * t, hi = std::min(n, per * (t + 1));
-    threads.emplace_back([&, t, lo, hi] { ok[(size_t)t] = span(lo, hi); });
-  }
-  ok[0] = span(0, std::min(per, n));
-  for (auto& th : threads) th.join();
-  for (int t = 0; t < T; ++t)
-    if (!ok[(size_t)t]) return -1;
-  return 0;
-}
-
-// ---------------------------------------------------------------------------
 // Batch page-header scan: walk a column chunk's compact-thrift PageHeader
 // stream in one native call (SURVEY.md §3.1 file walk — the reference's
 // ReadPageHeader loop; per-page Python thrift parsing was the measured
@@ -1661,17 +1552,9 @@ extern "C" int64_t pq_count_target_in_runs(
 }
 
 // ---------------------------------------------------------------------------
-// Fused whole-chunk dictionary-index scan (SURVEY.md §3.1 hot path): one
-// native call replaces the per-page Python loop of build_plan for the host
-// dict route — per page: decompress (snappy/zstd via dlopen'd system libs,
-// the same ones codecs/ uses from Python), verify the def-level stream is
-// all-present, and scan the RLE/bit-packed index runs into ONE combined
-// chunk-level run table whose byte offsets index the decompressed stream.
-// ~400 pages of a 64 MB chunk cost ~40 ms of Python/ctypes dispatch on the
-// per-page path; this pass is one call.  Any page this scan can't prove
-// simple (nulls, rep levels, non-dict encoding, foreign codec, legacy
-// BIT_PACKED levels) bails the WHOLE chunk back to the Python planner,
-// which owns the general semantics.
+// Page decompression without Python: snappy (a fast in-tree decoder, the
+// dlopen'd system libsnappy as its fallback) and zstd via the dlopen'd
+// system libzstd — the same libraries codecs/ uses from Python.
 // ---------------------------------------------------------------------------
 
 #include <dlfcn.h>
@@ -1885,216 +1768,9 @@ inline bool page_decompress(int codec, const uint8_t* src, int64_t src_len,
   return false;
 }
 
-inline int level_bit_width(int32_t max_level) {
-  int w = 0;
-  while ((1 << w) - 1 < max_level) ++w;
-  return w;
-}
-
-// Parse a def-level RLE stream and require it to be a single RLE run of
-// `max_def` covering >= nvals values (the all-present page). Returns false
-// for anything else (caller bails the chunk).
-inline bool def_stream_all_present(const uint8_t* p, int64_t len,
-                                   int64_t nvals, int32_t max_def) {
-  int w = level_bit_width(max_def);
-  int64_t pos = 0;
-  uint64_t header = 0;
-  int shift = 0;
-  while (true) {
-    if (pos >= len) return false;
-    uint8_t b = p[pos++];
-    header |= (uint64_t)(b & 0x7F) << shift;
-    if (!(b & 0x80)) break;
-    shift += 7;
-    if (shift > 63) return false;
-  }
-  if (header & 1) return false;  // bit-packed run: not the all-present shape
-  int64_t count = (int64_t)(header >> 1);
-  if (count < nvals) return false;
-  const int vbytes = (w + 7) / 8;
-  if (pos + vbytes > len) return false;
-  uint64_t value = 0;
-  for (int j = 0; j < vbytes; ++j) value |= (uint64_t)p[pos + j] << (8 * j);
-  if (w < 64) value &= (1ull << w) - 1;
-  return (int64_t)value == (int64_t)max_def;
-}
-
-struct DictPageScan {
-  int64_t nvals = 0;     // data values in this page
-  int64_t run_base = 0;  // first run slot in the shared output arrays
-  int64_t nruns = 0;     // runs written
-  int64_t out_base = 0;  // page body base in out_bytes
-  int ok = 1;            // 0 = bail the chunk
-};
-
 }  // namespace
 
 extern "C" {
-
-// Returns total run count (>= 0), or a bail code: -1 malformed, -2 page
-// shape outside the fused fast path (caller falls back to the Python
-// planner), -3 insufficient capacity.  out_info = {nvals_total, bytes_used}.
-// `pages` rows use the pq_scan_page_headers layout (PG_* columns).
-int64_t pq_dict_chunk_scan(const uint8_t* chunk, int64_t chunk_len,
-                           const int64_t* pages, int64_t n_pages,
-                           int32_t codec, int32_t max_def, int32_t max_rep,
-                           uint8_t* out_bytes, int64_t out_cap,
-                           int64_t* ends, uint8_t* kinds, int64_t* payloads,
-                           int64_t* boffs, int32_t* widths, int64_t run_cap,
-                           int64_t* out_info, int32_t nthreads) {
-  if (max_rep > 0) return -2;
-  if (codec != 0 && codec != 1 && codec != 6) return -2;
-  std::vector<DictPageScan> ps((size_t)n_pages);
-  // layout pass: per-page output/run bases so the parallel phase is
-  // write-disjoint. Run capacity per page = nvals + 1 (every run covers >= 1
-  // of the page's values, +1 for the width-0 synthetic run).
-  int64_t bytes_total = 0, runs_total_cap = 0, nvals_total = 0;
-  for (int64_t i = 0; i < n_pages; ++i) {
-    const int64_t* row = pages + i * PG_NFIELDS;
-    const int64_t pt = row[PG_TYPE];
-    DictPageScan& s = ps[(size_t)i];
-    if (pt != 0 && pt != 3) continue;  // dict page handled by caller
-    const int64_t enc = row[PG_ENC];
-    if (enc != 2 && enc != 8) return -2;  // not PLAIN_/RLE_DICTIONARY
-    if (pt == 0 && max_def > 0 && row[PG_DEF_ENC] != 3) return -2;  // legacy
-    if (pt == 3 && max_def > 0 && row[PG_NNULLS] != 0) return -2;
-    s.nvals = row[PG_NVALS];
-    if (s.nvals < 0) return -1;
-    s.out_base = bytes_total;
-    s.run_base = runs_total_cap;
-    int64_t body_uncomp = row[PG_UNCOMP];
-    if (pt == 3) {
-      const int64_t rl = row[PG_RL_BYTES] < 0 ? 0 : row[PG_RL_BYTES];
-      const int64_t dl = row[PG_DL_BYTES] < 0 ? 0 : row[PG_DL_BYTES];
-      body_uncomp -= rl + dl;
-    }
-    if (body_uncomp < 0) return -1;
-    bytes_total += body_uncomp;
-    runs_total_cap += s.nvals + 1;
-    nvals_total += s.nvals;
-  }
-  if (bytes_total > out_cap || runs_total_cap > run_cap) return -3;
-
-  std::atomic<bool> bail{false};
-  auto scan_page_impl = [&](int64_t i) -> bool {
-    const int64_t* row = pages + i * PG_NFIELDS;
-    const int64_t pt = row[PG_TYPE];
-    DictPageScan& s = ps[(size_t)i];
-    if (pt != 0 && pt != 3) return true;  // dict page handled by caller
-    const int64_t dpos = row[PG_DATA_POS];
-    const int64_t clen = row[PG_COMP];
-    if (dpos < 0 || clen < 0 || dpos + clen > chunk_len) return false;
-    const uint8_t* payload = chunk + dpos;
-    uint8_t* body = out_bytes + s.out_base;
-    int64_t body_len;
-    int64_t pos = 0;  // index-section start within body
-    if (pt == 0) {
-      body_len = row[PG_UNCOMP];
-      if (!page_decompress(codec, payload, clen, body, body_len))
-        return false;
-      if (max_def > 0) {
-        if (pos + 4 > body_len) return false;
-        uint32_t dl;
-        std::memcpy(&dl, body + pos, 4);
-        if (pos + 4 + (int64_t)dl > body_len) return false;
-        if (!def_stream_all_present(body + pos + 4, dl, s.nvals, max_def))
-          return false;
-        pos += 4 + dl;
-      }
-    } else {  // v2: levels sit uncompressed ahead of the body
-      const int64_t rl = row[PG_RL_BYTES] < 0 ? 0 : row[PG_RL_BYTES];
-      const int64_t dl = row[PG_DL_BYTES] < 0 ? 0 : row[PG_DL_BYTES];
-      if (rl + dl > clen) return false;
-      body_len = row[PG_UNCOMP] - rl - dl;
-      const int page_codec = row[PG_IS_COMPRESSED] == 0 ? 0 : codec;
-      if (!page_decompress(page_codec, payload + rl + dl, clen - rl - dl,
-                           body, body_len))
-        return false;
-    }
-    if (s.nvals == 0) { s.nruns = 0; return true; }
-    if (pos >= body_len) return false;
-    const int w = body[pos];
-    ++pos;
-    uint8_t* pk = kinds + s.run_base;
-    int64_t* pp = payloads + s.run_base;
-    int64_t* pb = boffs + s.run_base;
-    int32_t* pw = widths + s.run_base;
-    int64_t* pe = ends + s.run_base;  // holds per-run COUNTS until merge
-    if (w == 0) {  // single-entry dictionary: one synthetic RLE run
-      pk[0] = 0;
-      pp[0] = 0;
-      pb[0] = s.out_base;
-      pw[0] = 1;
-      pe[0] = s.nvals;
-      s.nruns = 1;
-      return true;
-    }
-    if (w > 32) return false;
-    int64_t k = pq_scan_rle_runs(body + pos, body_len - pos, s.nvals, w, pk,
-                                 pe, pp, pb);
-    if (k < 0 || k > s.nvals + 1) return false;
-    for (int64_t r = 0; r < k; ++r) {
-      pb[r] += s.out_base + pos;  // relative -> absolute in out_bytes
-      pw[r] = w;
-    }
-    s.nruns = k;
-    return true;
-  };
-  // a single failed page bails the WHOLE chunk to the Python planner, so
-  // stop decompressing remaining pages as soon as any worker fails
-  auto scan_page = [&](int64_t i) {
-    if (bail.load(std::memory_order_relaxed)) return;
-    if (!scan_page_impl(i)) {
-      ps[(size_t)i].ok = 0;
-      bail.store(true, std::memory_order_relaxed);
-    }
-  };
-
-  int T = nthreads;
-  if (T < 1) T = 1;
-  if (T > 16) T = 16;
-  if ((int64_t)T > n_pages) T = (int)n_pages ? (int)n_pages : 1;
-  if (T <= 1) {
-    for (int64_t i = 0; i < n_pages; ++i) scan_page(i);
-  } else {
-    std::vector<std::thread> threads;
-    std::atomic<int64_t> next{0};
-    auto worker = [&] {
-      int64_t i;
-      while ((i = next.fetch_add(1)) < n_pages) scan_page(i);
-    };
-    for (int t = 1; t < T; ++t) threads.emplace_back(worker);
-    worker();
-    for (auto& th : threads) th.join();
-  }
-  for (int64_t i = 0; i < n_pages; ++i)
-    if (!ps[(size_t)i].ok) return -2;
-
-  // merge: compact the per-page run slices down to a contiguous table and
-  // turn per-run counts into cumulative ends.
-  int64_t nruns = 0, total = 0;
-  for (int64_t i = 0; i < n_pages; ++i) {
-    const DictPageScan& s = ps[(size_t)i];
-    if (!s.nruns) continue;
-    if (nruns != s.run_base) {
-      std::memmove(kinds + nruns, kinds + s.run_base, (size_t)s.nruns);
-      std::memmove(payloads + nruns, payloads + s.run_base,
-                   (size_t)s.nruns * 8);
-      std::memmove(boffs + nruns, boffs + s.run_base, (size_t)s.nruns * 8);
-      std::memmove(widths + nruns, widths + s.run_base, (size_t)s.nruns * 4);
-      std::memmove(ends + nruns, ends + s.run_base, (size_t)s.nruns * 8);
-    }
-    for (int64_t r = 0; r < s.nruns; ++r) {
-      total += ends[nruns + r];
-      ends[nruns + r] = total;
-    }
-    nruns += s.nruns;
-  }
-  if (total != nvals_total) return -1;
-  out_info[0] = nvals_total;
-  out_info[1] = bytes_total;
-  return nruns;
-}
 
 // ---------------------------------------------------------------------------
 // Batched PLAIN BYTE_ARRAY parse: many pages' 4-byte-length-prefixed

@@ -38,12 +38,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..format import metadata as md
-from ..format.enums import CompressionCodec, Encoding, PageType, Type
+from ..format.enums import Encoding, FieldRepetitionType as Rep, PageType, Type
 from ..io.column import Column
 from ..io.reader import ColumnChunkReader, CorruptedError, decode_chunk_host, _bit_width
 from ..obs import trace as _otrace
 from ..ops import device as dev, levels as levels_ops, ref
+from ..schema.types import LogicalKind
 from ..utils.debug import counters
 from .. import native
 
@@ -351,10 +351,6 @@ class _Plan:
     dense_w: Optional[int] = None
     dense_pages: List[Tuple[int, int]] = field(default_factory=list)
     dense_ok: bool = True
-    # dict-chunk decode route, decided ONCE at plan time (build_plan) so a
-    # mid-flight env flip cannot make stage/decode disagree with the plan's
-    # dense accumulation decision
-    dict_route: Optional[str] = None
     # delta
     d_firsts: List[int] = field(default_factory=list)
     d_counts: List[int] = field(default_factory=list)
@@ -424,88 +420,6 @@ def _single_rle_run(body, n: int, w: int):
     return value, i + vbytes
 
 
-def _fused_dict_plan(reader: ColumnChunkReader):
-    """One-native-call planner for the host dict route: whole-chunk
-    decompress + all-present level check + index-run scan fused in C++
-    (native.dict_chunk_scan).  Returns ``(plan, raw)`` on success and
-    ``(None, raw_or_None)`` whenever the chunk needs the general per-page
-    planner — nulls, rep levels, PLAIN-fallback pages, codecs outside
-    UNCOMPRESSED/SNAPPY/ZSTD, registry-shadowed encodings, or no native
-    lib; ``raw`` hands the already-read chunk buffer to the fallback so
-    the bail path doesn't pread the chunk twice."""
-    from ..ops.encodings import is_builtin_decode
-
-    leaf = reader.leaf
-    meta = reader.meta
-    if leaf.max_repetition_level != 0:
-        return None, None
-    if _dict_run_route() != "host":
-        return None, None
-    codec_id = int(meta.codec)
-    if codec_id not in (int(CompressionCodec.UNCOMPRESSED),
-                        int(CompressionCodec.SNAPPY),
-                        int(CompressionCodec.ZSTD)):
-        return None, None
-    from ..codecs import SnappyCodec, UncompressedCodec, ZstdCodec
-
-    if type(reader.codec) not in (UncompressedCodec, SnappyCodec, ZstdCodec):
-        # a substituted/subclassed codec (codecs.CODECS is an override
-        # point) must keep decoding through reader.codec, not the raw
-        # libsnappy/libzstd the native pass dlopens
-        return None, None
-    encs = set(meta.encodings or ())
-    if not ({int(Encoding.RLE_DICTIONARY), int(Encoding.PLAIN_DICTIONARY)}
-            & encs):
-        return None, None
-    if not (is_builtin_decode(Encoding.RLE_DICTIONARY)
-            and is_builtin_decode(Encoding.PLAIN_DICTIONARY)):
-        return None, None
-    start, size = reader.byte_range
-    raw = reader.file.source.pread_view(start, size)
-    rows = native.scan_page_headers(raw, meta.num_values)
-    if rows is None:
-        return None, raw
-    res = native.dict_chunk_scan(raw, rows, codec_id,
-                                 leaf.max_definition_level,
-                                 leaf.max_repetition_level)
-    if res is None:
-        return None, raw
-    ends, kinds, payloads, bit_offs, widths, nvals, body = res
-    physical = Type(meta.type)
-    plan = _Plan()
-    plan.leaf = leaf
-    plan.physical = physical
-    plan.set_kind("dict")
-    plan.dict_route = "host"
-    plan.dense_ok = False
-    # dictionary page decode stays in Python (one small page)
-    for row in rows:
-        if row[native.PG_TYPE] == PageType.DICTIONARY_PAGE:
-            rawv = raw if isinstance(raw, np.ndarray) else np.frombuffer(
-                raw, np.uint8)
-            payload = rawv[row[native.PG_DATA_POS]:
-                           row[native.PG_DATA_POS] + row[native.PG_COMP]]
-            dbody = _decompress(reader.codec, payload,
-                                int(row[native.PG_UNCOMP]))
-            plan.dictionary_host = ref.decode_plain(
-                np.frombuffer(dbody, np.uint8),
-                int(row[native.PG_DICT_NVALS]), physical, leaf.type_length)
-            break
-    v = plan.vruns
-    v.ends.append(ends)
-    v.kinds.append(kinds)
-    v.payloads.append(payloads)
-    v.bit_offsets.append(bit_offs)
-    v.widths.append(widths)
-    v.total = nvals
-    v.nbytes = len(body)
-    plan.values.extend(body)
-    plan.total_slots = nvals   # all-present proven by the native scan
-    plan.total_values = nvals
-    counters.inc("fused_dict_plans")
-    return plan, raw
-
-
 def _decompress(codec, payload, size: int):
     """One page's ``codec.decode`` inside a ``decompress`` span: the host
     codec time of the staging phase."""
@@ -519,11 +433,6 @@ def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
     ``pages`` (an iterator of PageInfo, e.g. from io/search.seek_pages)
     restricts the plan to a page subset — the pushdown scan path; the
     dictionary page must be included when the chunk is dict-encoded."""
-    chunk_raw = None
-    if pages is None:
-        fused, chunk_raw = _fused_dict_plan(reader)
-        if fused is not None:
-            return fused
     leaf = reader.leaf
     codec = reader.codec
     physical = Type(reader.meta.type)
@@ -533,7 +442,7 @@ def build_plan(reader: ColumnChunkReader, pages=None) -> _Plan:
     plan.leaf = leaf
     plan.physical = physical
 
-    for page in (reader.pages(raw=chunk_raw) if pages is None else pages):
+    for page in (reader.pages() if pages is None else pages):
         h = page.header
         pt = page.page_type
         if pt == PageType.DICTIONARY_PAGE:
@@ -621,59 +530,6 @@ def _dense_mode() -> str:
     return "auto"
 
 
-def _backend_route(env_var: str) -> str:
-    """Shared host/device routing policy: an explicit env override wins,
-    else 'device' on a real TPU and 'host' on every other backend (where
-    the XLA emulation of gather/bitcast-shaped kernels is the measured
-    pathological case)."""
-    from ..utils.env import env_str
-
-    v = env_str(env_var).lower()
-    if v in ("host", "device"):
-        return v
-    return "device" if jax.default_backend() == "tpu" else "host"
-
-
-def _plain_run_route() -> str:
-    """Where PLAIN fixed-width chunks decode: 'device' (staged bitcast
-    kernels — the bytes are needed in HBM anyway) or 'host' (numpy
-    zero-copy views of the host accumulation; staging + an XLA bitcast
-    materialization are two pure memcpy passes for an op numpy does for
-    free).  PARQUET_TPU_PLAIN_RUNS overrides."""
-    return _backend_route("PARQUET_TPU_PLAIN_RUNS")
-
-
-def _dict_run_route() -> str:
-    """Where mixed-run dictionary index streams decode: 'device' (the
-    rle_expand kernel) or 'host' (C++ fused run expand + gather; BASELINE
-    config 2 was the emulated route's worst case).  PARQUET_TPU_DICT_RUNS
-    overrides."""
-    return _backend_route("PARQUET_TPU_DICT_RUNS")
-
-
-def _bss_run_route() -> str:
-    """Where BYTE_STREAM_SPLIT chunks decode: 'device' (static per-page
-    plane-slice kernels) or 'host' (numpy plane transpose — one pass per
-    page).  PARQUET_TPU_BSS_RUNS overrides."""
-    return _backend_route("PARQUET_TPU_BSS_RUNS")
-
-
-def _dba_run_route() -> str:
-    """Where DELTA_BYTE_ARRAY chunks decode: 'device' (host prefix-length
-    prescan, suffix gather + pointer-jumping prefix resolution on chip —
-    only length metadata is touched on host) or 'host' (the sequential
-    front-coding expand).  PARQUET_TPU_DBA_RUNS overrides."""
-    return _backend_route("PARQUET_TPU_DBA_RUNS")
-
-
-def _delta_run_route() -> str:
-    """Where DELTA_BINARY_PACKED chunks decode: 'device' (dense unpack +
-    segmented cumsum kernels) or 'host' (C++ fused unpack + prefix sum from
-    the prescan miniblock tables; BASELINE config 4).
-    PARQUET_TPU_DELTA_RUNS overrides."""
-    return _backend_route("PARQUET_TPU_DELTA_RUNS")
-
-
 def _use_pallas(w: int) -> bool:
     """Whether the dense unpack of a ``w``-bit stream runs the Pallas kernel:
     on a TPU backend by default ('auto'), anywhere when forced ('pallas' —
@@ -726,13 +582,6 @@ def _stage_values(plan: _Plan, raw: np.ndarray, pos: int, nvals: int,
             f"encoding {encoding!r} is overridden by a registered decoder")
     if encoding in (Encoding.PLAIN_DICTIONARY, Encoding.RLE_DICTIONARY):
         plan.set_kind("dict")
-        if plan.dict_route is None:
-            plan.dict_route = _dict_run_route()
-        if plan.dict_route == "host":
-            # the fused C++ expand+gather outruns the emulated dense-unpack
-            # kernels off-TPU; don't pay the dense compaction accumulation
-            # for a stream that will decode from the run tables
-            plan.dense_ok = False
         width = int(raw[pos]) if pos < len(raw) else 0
         body = raw[pos + 1 :]
         base = len(plan.values)
@@ -809,28 +658,20 @@ def _stage_values(plan: _Plan, raw: np.ndarray, pos: int, nvals: int,
         plan.host_parts.append((v, o))
         return
     if encoding == Encoding.DELTA_BYTE_ARRAY:
-        if _dba_run_route() == "device":
-            plan.set_kind("dba")
-            plens, suffixes, soffs, _ = dev.delta_byte_array_prescan(raw, pos)
-            if len(plens) and int(plens[0]) != 0:
-                # front coding is per-page (first entry stores its full
-                # value); a nonzero leading prefix would chase a parent
-                # in another page — malformed, let the host path raise
-                # its precise error
-                raise _Unsupported(
-                    "delta byte array page with nonzero leading prefix")
-            base = len(plan.values)
-            plan.values.extend(suffixes)
-            plan.dba_plens.append(plens)
-            plan.dba_soffs.append(soffs.astype(np.int64))
-            plan.dba_pages.append((base, len(plens)))
-            return
-        plan.set_kind("host_ba")
-        v, o, _ = ref.decode_delta_byte_array(raw, pos)
-        if physical == Type.FIXED_LEN_BYTE_ARRAY:
-            plan.host_parts.append(v.reshape(-1, leaf.type_length))
-        else:
-            plan.host_parts.append((v, o))
+        plan.set_kind("dba")
+        plens, suffixes, soffs, _ = dev.delta_byte_array_prescan(raw, pos)
+        if len(plens) and int(plens[0]) != 0:
+            # front coding is per-page (first entry stores its full
+            # value); a nonzero leading prefix would chase a parent
+            # in another page — malformed, let the host path raise
+            # its precise error
+            raise _Unsupported(
+                "delta byte array page with nonzero leading prefix")
+        base = len(plan.values)
+        plan.values.extend(suffixes)
+        plan.dba_plens.append(plens)
+        plan.dba_soffs.append(soffs.astype(np.int64))
+        plan.dba_pages.append((base, len(plens)))
         return
     raise _Unsupported(f"encoding {encoding!r}")
 
@@ -1109,28 +950,10 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
                      put=None) -> tuple:
     if put is None:
         put = jax.device_put
-    # host value routes, decided BEFORE the device size guard (they read
-    # the host accumulation directly — no 32-bit-lane constraint) and
-    # recorded in the staged meta: decode must not re-derive routing from
-    # mutable env/backend state and disagree with what was (not) staged.
-    # The host dict route outranks the dense device route off-TPU (measured
-    # 2.4x on the 200-entry-dictionary string config).  The route was fixed
-    # at plan time (plan.dict_route) — mid-flight env flips cannot make the
-    # stage disagree with the plan's dense accumulation decision.
-    dict_host = (plan.value_kind == "dict"
-                 and (plan.dict_route or _dict_run_route()) == "host")
-    dense_route = (plan.value_kind == "dict" and not dict_host
-                   and plan.dense_ok and plan.dense_pages
-                   and _dense_mode() != "off")
-    plain_host = (plan.value_kind in ("plain_fixed", "plain_flba")
-                  and _plain_run_route() == "host")
-    delta_host = (plan.value_kind == "delta"
-                  and _delta_run_route() == "host"
-                  and native.get_lib() is not None)
-    bss_host = plan.value_kind == "bss" and _bss_run_route() == "host"
-    host_value_route = dict_host or plain_host or delta_host or bss_host
-    if (stage_levels and len(plan.levels) > dev.MAX_DEVICE_BUF) or (
-            not host_value_route and len(plan.values) > dev.MAX_DEVICE_BUF):
+    dense_route = (plan.value_kind == "dict" and plan.dense_ok
+                   and plan.dense_pages and _dense_mode() != "off")
+    if len(plan.values) > dev.MAX_DEVICE_BUF or (
+            stage_levels and len(plan.levels) > dev.MAX_DEVICE_BUF):
         # device kernels index in 32-bit lanes; oversized chunks decode on host
         raise _Unsupported("chunk stream exceeds 32-bit-lane bit addressing")
     lev_dbuf = None
@@ -1138,19 +961,10 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
         lev_dbuf = put(plan.levels.padded_array())
         counters.inc("bytes_h2d", len(plan.levels))
     meta = {}
-    if dict_host:
-        meta["dict_host"] = True
-    if plain_host:
-        meta["plain_host"] = True
-    if delta_host:
-        meta["delta_host"] = True
-    if bss_host:
-        meta["bss_host"] = True
-    delta_dense = (plan.value_kind == "delta" and not delta_host
+    delta_dense = (plan.value_kind == "delta"
                    and _stage_delta_dense(plan, meta, put=put))
     val_dbuf = None
-    if not dense_route and not delta_dense and not dict_host and \
-            not plain_host and not delta_host and not bss_host and \
+    if not dense_route and not delta_dense and \
             plan.value_kind not in (None, "host_ba"):
         # staged even when empty (all-null chunks have no value bytes): the
         # kernels need a real buffer operand to slice [:0] from.  PLAIN
@@ -1164,13 +978,12 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
         # compacted single-width index stream replaces the raw bodies
         meta["dense"] = put(plan.dense.padded_array(extra=4))
         counters.inc("bytes_h2d", len(plan.dense))
-    if plan.value_kind == "delta" and not delta_host:
-        if not delta_dense:
-            if len(set(plan.d_vpms)) > 1:
-                # the gather kernel assumes one values-per-miniblock across
-                # all pages; reject before paying any H2D
-                raise _Unsupported("mixed delta miniblock sizes across pages")
-            meta["delta"] = put(_delta_gather_tables(plan))
+    if plan.value_kind == "delta" and not delta_dense:
+        if len(set(plan.d_vpms)) > 1:
+            # the gather kernel assumes one values-per-miniblock across
+            # all pages; reject before paying any H2D
+            raise _Unsupported("mixed delta miniblock sizes across pages")
+        meta["delta"] = put(_delta_gather_tables(plan))
     if plan.value_kind == "dba":
         # per-entry length tables ride to HBM with the suffix stream so
         # the decode phase is pure on-chip work
@@ -1182,7 +995,7 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
         meta["dictionary"] = _stage_dictionary(plan.dictionary_host,
                                                plan.physical, plan.leaf,
                                                put=put)
-    if plan.vruns.total and not dict_host:
+    if plan.vruns.total:
         meta["vruns"] = put(plan.vruns.run_arrays())
     if stage_levels and plan.def_runs.total:
         meta["def_runs"] = put(plan.def_runs.run_arrays())
@@ -1191,42 +1004,36 @@ def _stage_plan_impl(plan: _Plan, stage_levels: bool = True,
     return lev_dbuf, val_dbuf, meta
 
 
-def stage_levels_on_device(leaf, plan: _Plan) -> bool:
-    """Whether the level streams should go to HBM: flat single-def columns
-    (validity from device RLE expansion) and — behind
-    ``PARQUET_TPU_DEVICE_ASM=1`` — repeated columns of ANY depth, whose
-    offsets/validity then assemble on device via ``dev.assemble_nested``
-    (struct layers between lists collapse into the nearest list validity,
-    same as the host assembler).  Flat struct chains (max_def > 1, no
-    repetition) always expand on host: the table assembler needs host def
-    levels for struct nullness, so staging their bytes would be wasted H2D.
+def _lists_only(leaf) -> bool:
+    """Whether every group above ``leaf`` is list machinery: a LIST group
+    with one child, or the one-child repeated group directly under one.  A
+    struct or map layer anywhere in the chain fails the test."""
+    groups = leaf.ancestors[:-1]
+    for i, g in enumerate(groups):
+        if len(g.children) != 1:
+            return False
+        if g.logical_kind == LogicalKind.LIST:
+            continue
+        if (i and g.repetition == Rep.REPEATED
+                and groups[i - 1].logical_kind == LogicalKind.LIST):
+            continue
+        return False
+    return True
 
-    Repeated columns assemble on device by DEFAULT on accelerator
-    backends (offsets/validity land in HBM via ``dev.assemble_nested`` —
-    no host round-trip in the decode pipeline) and on HOST on the cpu
-    backend, where the compaction kernels are emulated scatter/sort and
-    measured 10-25x slower than the C++ expand+assemble pass (8M slots:
-    31 ms C++ vs 555-815 ms emulated).  ``PARQUET_TPU_DEVICE_ASM=1``
-    forces device assembly everywhere (the route-soak's device leg);
-    ``=0`` forces host assembly everywhere."""
+
+def stage_levels_on_device(leaf, plan: _Plan) -> bool:
+    """Whether the level streams should go to HBM, decided from the schema:
+    flat single-def columns with nulls (validity from device RLE expansion)
+    and repeated columns whose chain is lists only, whose offsets/validity
+    then assemble on device via ``dev.assemble_nested``.  Struct chains —
+    flat (max_def > 1) or with a struct/map layer around or inside the lists
+    — expand on host: the table assembler reads their host def levels for
+    struct nullness."""
     if leaf.max_repetition_level == 0:
         if plan.total_values == plan.total_slots:
             return False  # no nulls anywhere: validity is None, levels unused
         return leaf.max_definition_level <= 1
-    from ..utils.env import env_str
-
-    flag = env_str("PARQUET_TPU_DEVICE_ASM")
-    if flag == "0":
-        return False
-    if flag != "1":
-        import jax
-
-        if jax.default_backend() == "cpu":
-            return False
-    # any repetition depth: dev.assemble_nested mirrors the host assembler
-    # over expanded level streams (struct layers between lists collapse into
-    # the nearest list validity, same as the host semantics)
-    return (leaf.max_repetition_level >= 1
+    return (_lists_only(leaf)
             and bool(plan.def_runs.total) and bool(plan.rep_runs.total)
             and not plan.host_def)
 
@@ -1383,18 +1190,6 @@ def decode_chunk_batched(reader: ColumnChunkReader,
     if len(batches) < min_batches:
         raise _Unsupported("batched decode: chunk too small to pipeline")
     physical = Type(reader.meta.type)
-    first_hdr = data_pages[0].header if data_pages else None
-    first_enc = None
-    if first_hdr is not None:
-        dph = first_hdr.data_page_header or first_hdr.data_page_header_v2
-        if dph is not None and dph.encoding is not None:
-            first_enc = Encoding(dph.encoding)
-    if (first_enc == Encoding.PLAIN and _plain_run_route() == "host"
-            and (physical in _FIXED_WIDTH
-                 or physical == Type.FIXED_LEN_BYTE_ARRAY)):
-        # the plain host route decodes as a zero-copy view of ONE contiguous
-        # accumulation — per-batch splits would only re-buy the concat copy
-        raise _Unsupported("batched decode: plain host route is single-pass")
 
     def plan_batch(i: int, subset) -> _Plan:
         return build_plan(reader,
@@ -1590,10 +1385,10 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
 
     # ---- levels -----------------------------------------------------------
     # Flat optional columns: expand def levels on device (validity mask stays
-    # in HBM).  Simple single-level lists: expand AND assemble on device
-    # (SURVEY.md §7 hard part 4 — config 4's shape).  Struct chains and
-    # deeper nesting: the record assembler consumes levels on host, so
-    # expand them there once — no device work, no double expansion.
+    # in HBM).  List chains of any depth: expand AND assemble on device
+    # (SURVEY.md §7 hard part 4).  Struct chains: the table assembler
+    # consumes levels on host, so expand them there once — no device work,
+    # no double expansion (stage_levels_on_device).
     def_levels = None
     def_host = rep_host = None
     device_asm = None
@@ -1663,20 +1458,7 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
     nvals = plan.total_values
 
     if kind == "plain_fixed":
-        if staged_meta.get("plain_host"):
-            # NON-TPU backend: PLAIN fixed-width decode is a pure bitcast,
-            # which numpy does as a zero-copy VIEW of the host accumulation
-            # buffer — no H2D staging, no XLA output materialization (two
-            # whole-chunk copies saved; see _plain_run_route)
-            arr = plan.values.array()
-            if physical in _IS_PAIR:
-                values = arr[: nvals * 8].view(np.uint32).reshape(nvals, 2)
-            elif physical == Type.INT96:
-                values = arr[: nvals * 12].view(np.uint32).reshape(nvals, 3)
-            else:
-                dt = np.int32 if physical == Type.INT32 else np.float32
-                values = arr[: nvals * 4].view(dt)
-        elif physical in _IS_PAIR:
+        if physical in _IS_PAIR:
             counters.inc("kernel_bytes.fixed64_pairs", 16 * nvals)
             values = dev.fixed64_pairs(val_dbuf, nvals)
         elif physical == Type.INT96:
@@ -1686,12 +1468,8 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
             counters.inc("kernel_bytes.bitcast_fixed32", 8 * nvals)
             values = dev.bitcast_fixed32(val_dbuf, nvals, dt)
     elif kind == "plain_flba":
-        if staged_meta.get("plain_host"):
-            values = plan.values.array()[: nvals * leaf.type_length].reshape(
-                nvals, leaf.type_length)
-        else:
-            values = val_dbuf[: nvals * leaf.type_length].reshape(
-                nvals, leaf.type_length)
+        values = val_dbuf[: nvals * leaf.type_length].reshape(
+            nvals, leaf.type_length)
     elif kind == "bool":
         values = plan.vruns.expand(val_dbuf,
                                     tables=staged_meta.get("vruns")).astype(jnp.bool_)
@@ -1702,37 +1480,6 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
         if staged_meta.get("dense") is not None:
             dict_indices, values = _decode_dense_dict(plan, staged_meta["dense"],
                                                       dictionary, physical)
-        elif staged_meta.get("dict_host"):
-            # Mixed RLE/bit-packed index runs on a NON-TPU backend: the
-            # run expand + gather is gather-shaped work the host C++ does
-            # ~8x faster than the XLA CPU emulation of the device kernels
-            # (BASELINE config 2 was 0.12 GB/s on the emulated route).
-            # The TPU keeps the device kernels; routing is per-backend,
-            # overridable via PARQUET_TPU_DICT_RUNS.
-            counters.inc("dict_host_route")
-            vals_host = plan.values.array()
-            dict_indices = None
-            values = None
-            if physical != Type.BYTE_ARRAY and isinstance(
-                    plan.dictionary_host, np.ndarray):
-                # fused one-pass expand+gather (no index stream); indices
-                # stay None — every consumer gates on is_dictionary_encoded
-                values = native.expand_gather(
-                    vals_host, plan.vruns.tables_host(), plan.vruns.total,
-                    plan.dictionary_host)
-            if values is None:
-                idx_host = plan.vruns.expand_host(vals_host)
-                dict_indices = idx_host.astype(np.int32, copy=False)
-                if physical != Type.BYTE_ARRAY:
-                    gathered = ref.gather_dictionary(
-                        plan.dictionary_host, idx_host)
-                    values = (gathered[0] if isinstance(gathered, tuple)
-                              else gathered)
-            if values is not None and physical in _IS_PAIR:
-                # keep the device-path representation invariant (64-bit
-                # values as (n,2) uint32 pairs) — zero-copy view
-                values = np.ascontiguousarray(values).view(
-                    np.uint32).reshape(-1, 2)
         else:
             dict_indices = plan.vruns.expand(val_dbuf,
                                              tables=staged_meta.get("vruns"))
@@ -1741,30 +1488,7 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
             else:
                 values = dev.dict_gather(dictionary, dict_indices)
     elif kind == "delta":
-        if staged_meta.get("delta_host"):
-            # NON-TPU backend: fused C++ unpack + min-add + prefix sum from
-            # the prescan miniblock tables, one threaded pass — the XLA CPU
-            # emulation of the dense delta kernels was BASELINE config 4's
-            # bottleneck.  Handles per-page vpm (no single-vpm constraint).
-            counters.inc("delta_host_route")
-            lens = [len(w) for w in plan.d_mb_widths]
-            page_mb_start = np.zeros(len(lens) + 1, np.int64)
-            np.cumsum(lens, out=page_mb_start[1:])
-            vals = native.delta_decode(
-                plan.values.array(),
-                np.concatenate(plan.d_mb_offs) if plan.d_mb_offs
-                else np.zeros(0, np.int64),
-                np.concatenate(plan.d_mb_widths) if plan.d_mb_widths
-                else np.zeros(0, np.int32),
-                np.concatenate(plan.d_mb_mins) if plan.d_mb_mins
-                else np.zeros(0, np.int64),
-                page_mb_start, plan.d_firsts, plan.d_counts, plan.d_vpms)
-            if physical == Type.INT32:
-                values = vals.astype(np.int32)
-            else:
-                values = np.ascontiguousarray(vals).view(
-                    np.uint32).reshape(-1, 2)
-        elif staged_meta.get("delta_dense") is not None:
+        if staged_meta.get("delta_dense") is not None:
             streams, perm, mins, firsts = staged_meta["delta_dense"]
             vpm, gw, gk, pcounts = plan.d_dense_static
             use_pk = tuple(_use_pallas(w) for w in gw)
@@ -1791,48 +1515,28 @@ def _decode_staged(leaf, physical: Type, plan: _Plan, staged: tuple,
         if not flba and w not in (4, 8):
             # e.g. INT96: BSS is undefined for it — clean host fallback
             raise _Unsupported("byte-stream-split over unsupported width")
-        if staged_meta.get("bss_host"):
-            # NON-TPU backend: one plane transpose per page written straight
-            # into the preallocated chunk output — one copy total (measured
-            # 3x the emulated static-slice kernels)
-            buf = plan.values.array()
-            allb = np.empty((nvals, w), np.uint8)
-            pos = 0
-            for base, pn in plan.bss_pages:
-                planes = buf[int(base) : int(base) + pn * w].reshape(w, pn)
-                allb[pos : pos + pn] = planes.T
-                pos += pn
-            if flba:
-                values = allb
-            elif physical in _IS_PAIR:
-                values = allb.view(np.uint32).reshape(nvals, 2)
-            else:
-                dt = np.int32 if physical == Type.INT32 else np.float32
-                values = allb.view(dt).reshape(-1)
+        if len(plan.bss_pages) > 512:
+            # static per-page slicing unrolls O(pages) into the graph
+            raise _Unsupported(
+                "byte-stream-split chunk with huge page count")
+        if len(plan.bss_pages) == 1 and int(plan.bss_pages[0][0]) == 0:
+            # single-page chunk (the common writer layout): the
+            # canonical ops/device.py plane-transpose kernel — same
+            # math as the multi-page twin without its per-page
+            # static-slice unrolling
+            values = dev.byte_stream_split(
+                val_dbuf, nvals, w,
+                out_dtype=None if flba else
+                ("int32" if physical == Type.INT32 else "float32")
+                if w == 4 else "uint32")
         else:
-            if len(plan.bss_pages) > 512:
-                # static per-page slicing unrolls O(pages) into the graph
-                raise _Unsupported(
-                    "byte-stream-split chunk with huge page count")
-            if len(plan.bss_pages) == 1 and int(plan.bss_pages[0][0]) == 0:
-                # single-page chunk (the common writer layout): the
-                # canonical ops/device.py plane-transpose kernel — same
-                # math as the multi-page twin without its per-page
-                # static-slice unrolling
-                values = dev.byte_stream_split(
-                    val_dbuf, nvals, w,
-                    out_dtype=None if flba else
-                    ("int32" if physical == Type.INT32 else "float32")
-                    if w == 4 else "uint32")
-            else:
-                values = _bss_decode_multi(
-                    val_dbuf, nvals,
-                    tuple((int(b), int(n)) for b, n in plan.bss_pages),
-                    w, flba,
-                    # 4-byte output dtype follows the PHYSICAL type (an
-                    # INT32 BSS column is not a float32 — bug caught by
-                    # the route-equality test)
-                    dtype4="int32" if physical == Type.INT32 else "float32")
+            values = _bss_decode_multi(
+                val_dbuf, nvals,
+                tuple((int(b), int(n)) for b, n in plan.bss_pages),
+                w, flba,
+                # 4-byte output dtype follows the PHYSICAL type (an
+                # INT32 BSS column is not a float32)
+                dtype4="int32" if physical == Type.INT32 else "float32")
     elif kind == "dba":
         staged_dba = staged_meta.get("dba")
         if staged_dba is None:

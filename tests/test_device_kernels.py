@@ -335,9 +335,9 @@ class TestAssembleNested:
         t = pa.table({"vv": pa.array(rows, pa.list_(pa.list_(pa.int64())))})
         self._compare(t, "vv")
 
-    def test_device_route_end_to_end_depth2(self, rng, monkeypatch):
-        """Full device decode with PARQUET_TPU_DEVICE_ASM=1 equals the host
-        read for a depth-2 column (VERDICT r3 task 6 'done =' bar)."""
+    def test_device_route_end_to_end_depth2(self, rng):
+        """Full device decode equals the host read for a depth-2 column:
+        a list chain assembles on device (VERDICT r3 task 6 'done =' bar)."""
         import io
 
         import pyarrow as pa
@@ -346,7 +346,6 @@ class TestAssembleNested:
         from parquet_tpu.io.reader import ParquetFile
         from parquet_tpu.parallel import device_reader as dr
 
-        monkeypatch.setenv("PARQUET_TPU_DEVICE_ASM", "1")
         n = 3000
         rows = [[list(map(int, rng.integers(0, 50, int(rng.integers(0, 3)))))
                  for _ in range(int(rng.integers(0, 4)))]

@@ -135,17 +135,13 @@ def test_device_matches_host_exactly(rng):
     assert devi["s"].to_arrow().cast(pa.string()).equals(host["s"].to_arrow().cast(pa.string()))
 
 
-def test_single_list_assembles_on_device(monkeypatch):
-    """Config-4 shape: with PARQUET_TPU_DEVICE_ASM=1, one-level list columns
-    expand levels AND assemble (validity, list_offsets) on device (VERDICT r1
-    item 7). The default keeps levels on host (C++ expand+assemble is far
-    cheaper than device compaction kernels — measured on v5e)."""
+def test_single_list_assembles_on_device():
+    """Config-4 shape: one-level list columns expand levels AND assemble
+    (validity, list_offsets) on device (VERDICT r1 item 7), on every
+    backend."""
     import jax
 
-    from parquet_tpu.ops import levels as levels_ops
     from parquet_tpu.parallel import device_reader as dr
-
-    monkeypatch.setenv("PARQUET_TPU_DEVICE_ASM", "1")
 
     rng = np.random.default_rng(13)
     n_lists = 5000
@@ -180,17 +176,47 @@ def test_single_list_assembles_on_device(monkeypatch):
     assert got.to_pylist() == want.to_pylist() == t.column("xs").to_pylist()
 
 
-def test_list_under_struct_keeps_host_levels_device_read():
-    """Lists below a struct layer must NOT take the device-assembly path:
-    the table assembler needs host def levels for struct nullness."""
-    rows = [{"xs": [1, 2]}, None, {"xs": None}, {"xs": [3]}] * 50
-    t = pa.table({"s": pa.array(rows,
-                                type=pa.struct([("xs", pa.list_(pa.int64()))]))})
-    buf = io.BytesIO()
-    pq.write_table(t, buf, use_dictionary=False)
-    got = ParquetFile(buf.getvalue()).read(device=True).to_arrow()
-    want = pq.read_table(io.BytesIO(buf.getvalue()))
-    assert got.column("s").to_pylist() == want.column("s").to_pylist()
+_NESTED_SHAPES = {
+    "struct<list<int64>>": (
+        pa.struct([("xs", pa.list_(pa.int64()))]),
+        [{"xs": [1, 2]}, None, {"xs": None}, {"xs": [3]}, {"xs": []}]),
+    "struct<list<string>>": (
+        pa.struct([("xs", pa.list_(pa.string()))]),
+        [{"xs": ["a", None]}, None, {"xs": None}, {"xs": ["bc"]},
+         {"xs": []}]),
+    "list<struct<int64>>": (
+        pa.list_(pa.struct([("v", pa.int64())])),
+        [[{"v": 1}, None, {"v": None}], None, [], [{"v": 4}]]),
+    "list<struct<list<int64>>>": (
+        pa.list_(pa.struct([("xs", pa.list_(pa.int64()))])),
+        [[{"xs": [1, None]}, None, {"xs": None}], None, [],
+         [{"xs": []}, {"xs": [5]}]]),
+    "list<list<int64>>": (
+        pa.list_(pa.list_(pa.int64())),
+        [[[1, 2], None, []], None, [], [[None, 3]]]),
+}
+
+
+@pytest.mark.parametrize("shape", list(_NESTED_SHAPES))
+def test_list_under_struct_keeps_host_levels_device_read(shape):
+    """A repeated leaf with a struct layer anywhere in its chain keeps host
+    levels (the table assembler reads them for struct nullness); a chain of
+    lists only assembles on device and carries no host def levels.  Each
+    shape reads back as pyarrow reads it."""
+    from parquet_tpu.parallel import device_reader as dr
+
+    typ, rows = _NESTED_SHAPES[shape]
+    t = pa.table({"c": pa.array(rows * 40, type=typ)})
+    raw = _write(t, use_dictionary=False)
+    got = ParquetFile(raw).read(device=True)
+    want = pq.read_table(io.BytesIO(raw))
+    assert got.to_arrow().column("c").to_pylist() \
+        == want.column("c").to_pylist()
+    chunk = ParquetFile(raw).row_group(0).column(0)
+    on_device = dr.stage_levels_on_device(chunk.leaf, dr.build_plan(chunk))
+    assert on_device is (shape == "list<list<int64>>")
+    if on_device:
+        assert got[chunk.leaf.dotted_path].def_levels is None
 
 
 @pytest.mark.parametrize("mode", ["off", "", "0", "1"])
@@ -217,9 +243,6 @@ def test_dense_dict_small_dictionary_pallas(monkeypatch, rng):
     from parquet_tpu.parallel import device_reader as dr
 
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "1")
-    # pin the DEVICE dict route: off-TPU the host route outranks the dense
-    # path this test exists to exercise
-    monkeypatch.setenv("PARQUET_TPU_DICT_RUNS", "device")
     n = 30000
     t = pa.table({"v": pa.array((rng.integers(0, 50, n) * 3).astype(np.int32))})
     raw = _write(t, use_dictionary=True, data_page_size=1 << 14)
@@ -440,22 +463,10 @@ def test_bytearray_source_mutation_safe(rng):
     np.testing.assert_array_equal(got, vals)
 
 
-@pytest.mark.parametrize("route_var,table_kind", [
-    ("PARQUET_TPU_DELTA_RUNS", "delta"),
-    ("PARQUET_TPU_DICT_RUNS", "dict"),
-    ("PARQUET_TPU_PLAIN_RUNS", "plain"),
-])
-def test_device_route_pinned_equals_host_route(route_var, table_kind, rng,
-                                               monkeypatch):
-    """The DEVICE value routes keep CPU coverage even though host routes are
-    the non-TPU default (review r4): pin each route to 'device' and assert
-    equality with the host-route decode."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.io.reader import ParquetFile
+@pytest.mark.parametrize("table_kind", ["delta", "dict", "plain"])
+def test_device_route_pinned_equals_host_route(table_kind, rng):
+    """The device decode of each value kind equals the host reader's decode
+    and pyarrow's."""
     from parquet_tpu.parallel import device_reader as dr
 
     n = 150_000
@@ -473,93 +484,111 @@ def test_device_route_pinned_equals_host_route(route_var, table_kind, rng,
         t = pa.table({"c": pa.array(rng.integers(0, 1 << 50, n))})
         kw = dict(compression="none", use_dictionary=False,
                   column_encoding={"c": "PLAIN"})
-    b = io.BytesIO()
-    pq.write_table(t, b, row_group_size=1 << 30, **kw)
-    raw = b.getvalue()
-
-    monkeypatch.setenv(route_var, "device")
+    raw = _write(t, row_group_size=1 << 30, **kw)
     dev_col = dr.decode_chunk_device(
         ParquetFile(raw).row_group(0).column(0), fallback=False)
-    monkeypatch.setenv(route_var, "host")
-    host_col = dr.decode_chunk_device(
-        ParquetFile(raw).row_group(0).column(0), fallback=False)
-    assert dev_col.to_arrow().equals(host_col.to_arrow())
+    host = ParquetFile(raw).read()["c"].to_arrow()
+    assert dev_col.to_arrow().cast(host.type).equals(host)
     oracle = t.column("c").combine_chunks()
     assert dev_col.to_arrow().cast(oracle.type).equals(oracle)
 
 
 @pytest.mark.parametrize("dtype", ["f8", "f4", "i4", "f2"])
-def test_bss_route_pinned_equals_host_route(dtype, rng, monkeypatch):
-    """BSS device and host routes agree with each other and the oracle."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.io.reader import ParquetFile
+def test_bss_route_pinned_equals_host_route(dtype, rng):
+    """The BSS device decode equals the host reader's decode and pyarrow's
+    (FLOAT16 is FLBA(2): the byte-row form)."""
     from parquet_tpu.parallel import device_reader as dr
 
     n = 120_000
     if dtype == "i4":
         t = pa.table({"c": pa.array(
             rng.integers(-(2**31), 2**31, n).astype(np.int32))})
-    elif dtype == "f2":  # FLOAT16 -> FLBA(2): the FLBA host-route branch
+    elif dtype == "f2":
         t = pa.table({"c": pa.array(rng.random(n).astype(np.float16))})
     else:
         t = pa.table({"c": pa.array(
             rng.random(n).astype(np.float64 if dtype == "f8"
                                  else np.float32))})
-    b = io.BytesIO()
     try:
-        pq.write_table(t, b, compression="snappy", use_dictionary=False,
-                       column_encoding={"c": "BYTE_STREAM_SPLIT"},
-                       row_group_size=1 << 30, data_page_size=16 * 1024)
+        raw = _write(t, compression="snappy", use_dictionary=False,
+                     column_encoding={"c": "BYTE_STREAM_SPLIT"},
+                     row_group_size=1 << 30, data_page_size=16 * 1024)
     except Exception as e:  # pyarrow without extended-BSS support
         pytest.skip(f"pyarrow cannot BSS-encode {dtype}: {e}")
-    raw = b.getvalue()
-    monkeypatch.setenv("PARQUET_TPU_BSS_RUNS", "device")
     dev_col = dr.decode_chunk_device(
         ParquetFile(raw).row_group(0).column(0), fallback=False)
-    monkeypatch.setenv("PARQUET_TPU_BSS_RUNS", "host")
-    host_col = dr.decode_chunk_device(
-        ParquetFile(raw).row_group(0).column(0), fallback=False)
-    assert dev_col.to_arrow().equals(host_col.to_arrow())
+    host = ParquetFile(raw).read()["c"].to_arrow()
+    assert dev_col.to_arrow().cast(host.type).equals(host)
     oracle = t.column("c").combine_chunks()
     assert dev_col.to_arrow().cast(oracle.type).equals(oracle)
 
 
-def test_device_asm_default_is_backend_aware(monkeypatch):
-    """Unset: device nested assembly is ON for accelerator backends, OFF on
-    the cpu backend (where the compaction kernels are emulated and measured
-    10-25x slower than the C++ host assembler).  "1"/"0" force either way."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.io.reader import ParquetFile
-    from parquet_tpu.parallel import device_reader as dr
-
-    t = pa.table({"v": pa.array([[1, 2], [], None, [3]] * 64)})
-    buf = io.BytesIO()
-    pq.write_table(t, buf, use_dictionary=False)
-    chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
-    plan = dr.build_plan(chunk)
-    leaf = chunk.leaf
-
-    monkeypatch.delenv("PARQUET_TPU_DEVICE_ASM", raising=False)
-    assert dr.stage_levels_on_device(leaf, plan) is False  # cpu backend
-    monkeypatch.setenv("PARQUET_TPU_DEVICE_ASM", "1")
-    assert dr.stage_levels_on_device(leaf, plan) is True
-    monkeypatch.setenv("PARQUET_TPU_DEVICE_ASM", "0")
-    assert dr.stage_levels_on_device(leaf, plan) is False
-
-    # unset + non-cpu backend reported -> device assembly is the default
-    monkeypatch.delenv("PARQUET_TPU_DEVICE_ASM", raising=False)
+def test_device_asm_rule_is_backend_independent(monkeypatch):
+    """Whether a column's levels go to HBM follows from its schema alone:
+    the same answer whatever backend JAX reports."""
     import jax
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert dr.stage_levels_on_device(leaf, plan) is True
+    from parquet_tpu.parallel import device_reader as dr
+
+    t = pa.table({
+        "lst": pa.array([[1, 2], [], None, [3]] * 64),
+        "st": pa.array([{"xs": [1]}, None, {"xs": None}, {"xs": []}] * 64),
+        "opt": pa.array([1, None, 3, 4] * 64),
+        "dense": pa.array([1, 2, 3, 4] * 64),
+    })
+    rg = ParquetFile(_write(t, use_dictionary=False)).row_group(0)
+    want = {"lst": True, "st": False, "opt": True, "dense": False}
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        got = {}
+        for i, name in enumerate(t.column_names):
+            chunk = rg.column(i)
+            got[name] = dr.stage_levels_on_device(chunk.leaf,
+                                                  dr.build_plan(chunk))
+        assert got == want
+
+
+_ARRAY_KINDS = {
+    "plain_int64": (pa.array(np.arange(3000, dtype=np.int64) * 7),
+                    dict(use_dictionary=False)),
+    "plain_int32": (pa.array(np.arange(3000, dtype=np.int32)),
+                    dict(use_dictionary=False)),
+    "plain_double": (pa.array(np.linspace(0, 1, 3000)),
+                     dict(use_dictionary=False)),
+    "plain_flba": (pa.array([bytes([i % 251] * 4) for i in range(3000)],
+                            type=pa.binary(4)),
+                   dict(use_dictionary=False)),
+    "dict_int64": (pa.array(np.arange(3000, dtype=np.int64) % 37),
+                   dict(use_dictionary=True)),
+    "dict_string": (pa.array([f"s{i % 29}" for i in range(3000)]),
+                    dict(use_dictionary=True)),
+    "delta": (pa.array(np.cumsum(np.arange(3000, dtype=np.int64))),
+              dict(use_dictionary=False,
+                   column_encoding={"c": "DELTA_BINARY_PACKED"})),
+    "bss": (pa.array(np.linspace(0, 1, 3000, dtype=np.float32)),
+            dict(use_dictionary=False,
+                 column_encoding={"c": "BYTE_STREAM_SPLIT"})),
+    "dba": (pa.array([f"prefix-{i:05d}" for i in range(3000)]),
+            dict(use_dictionary=False,
+                 column_encoding={"c": "DELTA_BYTE_ARRAY"})),
+}
+
+
+@pytest.mark.parametrize("kind", list(_ARRAY_KINDS))
+def test_device_read_returns_jax_arrays(kind):
+    """``read(device=True)`` decodes every value kind on the device on
+    every backend: the values (or a dictionary column's indices) come back
+    as a ``jax.Array``, and the column reads back as written."""
+    import jax
+
+    arr, kw = _ARRAY_KINDS[kind]
+    t = pa.table({"c": arr})
+    raw = _write(t, **kw)
+    col = ParquetFile(raw).read(device=True)["c"]
+    held = (col.dict_indices if col.is_dictionary_encoded()
+            else col.values)
+    assert isinstance(held, jax.Array)
+    _check(raw, t)
 
 
 def _kernel_bytes_from_metadata(raw: bytes) -> dict:
@@ -631,11 +660,8 @@ def _kernel_runs_from_pages(raw: bytes) -> int:
 
 
 def _kernel_counter_file(monkeypatch, rng):
-    """A file whose device read runs both kernels, with the device routes
-    pinned: a PLAIN int64, a nullable double, a nullable dictionary int32,
-    several V2 pages per chunk."""
-    for knob in ("PARQUET_TPU_PLAIN_RUNS", "PARQUET_TPU_DICT_RUNS"):
-        monkeypatch.setenv(knob, "device")
+    """A file whose device read runs both kernels: a PLAIN int64, a nullable
+    double, a nullable dictionary int32, several V2 pages per chunk."""
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "off")  # indices via the runs
     n = 6000
     t = pa.table({
@@ -715,7 +741,7 @@ def _plain_fixed_file(physical, rng, shape):
 
 @pytest.mark.parametrize("shape", ["multipage", "nulls", "all_null"])
 @pytest.mark.parametrize("physical", list(_PLAIN_FIXED))
-def test_plain_fixed_stages_exact_words(physical, shape, monkeypatch, rng):
+def test_plain_fixed_stages_exact_words(physical, shape, rng):
     """A PLAIN fixed-width chunk on the device route stages its values as
     one uint32 array of exactly ``nvals * width / 4`` words (no padded
     bucket), ``bytes_h2d`` grows by exactly those bytes, and the read
@@ -725,7 +751,6 @@ def test_plain_fixed_stages_exact_words(physical, shape, monkeypatch, rng):
     from parquet_tpu.format.enums import Type
     from parquet_tpu.parallel import device_reader as dr
 
-    monkeypatch.setenv("PARQUET_TPU_PLAIN_RUNS", "device")
     raw, t = _plain_fixed_file(physical, rng, shape)
     chunk = ParquetFile(raw).row_group(0).column(0)
     assert Type(chunk.meta.type) == Type[physical]

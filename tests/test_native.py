@@ -376,51 +376,6 @@ def test_scan_page_headers_huge_size_no_crash(lib):
     assert native.scan_page_headers(raw, 10) is None
 
 
-def test_expand_gather_fused_parity(lib, rng):
-    """Fused expand+gather == expand_host + numpy gather, mixed run kinds,
-    all thread counts."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.io.reader import ParquetFile
-    from parquet_tpu.parallel import device_reader as dr
-
-    n = 300_000
-    # long repeats (RLE runs) + random spans (bit-packed runs)
-    v = rng.integers(0, 500, n)
-    v[: n // 3] = 7
-    v[n // 2 : n // 2 + n // 4] = 411
-    t = pa.table({"k": pa.array(v.astype(np.int64))})
-    b = io.BytesIO()
-    pq.write_table(t, b, compression="none", use_dictionary=True,
-                   row_group_size=1 << 30)
-    ch = ParquetFile(b.getvalue()).row_group(0).column(0)
-    plan = dr.build_plan(ch)
-    buf = plan.values.array()
-    idx = plan.vruns.expand_host(buf)
-    want = plan.dictionary_host[idx]
-    for nt in (1, 3, 8):
-        got = native.expand_gather(buf, plan.vruns.tables_host(),
-                                   plan.vruns.total, plan.dictionary_host,
-                                   nthreads=nt)
-        np.testing.assert_array_equal(got, want)
-
-
-def test_expand_gather_rejects_oob_index(lib):
-    """An RLE run pointing past the dictionary must raise, not read OOB."""
-    ends = np.array([10], np.int64)
-    kinds = np.array([0], np.uint8)
-    payloads = np.array([99], np.int64)  # dict has 4 entries
-    offs = np.array([0], np.int64)
-    widths = np.array([7], np.int32)
-    d = np.arange(4, dtype=np.int64)
-    with pytest.raises(ValueError):
-        native.expand_gather(np.zeros(16, np.uint8),
-                             (ends, kinds, payloads, offs, widths), 10, d)
-
-
 def test_scan_rle_runs_rejects_zero_count_runs(lib):
     """A zero-count run header covers no values and never decrements the
     scanner's remaining count — a crafted stream of them must fail fast
@@ -436,63 +391,6 @@ def test_scan_rle_runs_rejects_zero_count_runs(lib):
     stream2 = np.frombuffer(b"\x01" * 64, np.uint8)
     with pytest.raises(ValueError):
         native.scan_rle_runs(stream2, 8, 3)
-
-
-def test_dict_chunk_scan_matches_per_page_planner(lib, rng):
-    """The fused whole-chunk dict scan (one native call: decompress +
-    all-present level check + index-run scan) must produce a plan whose
-    decode equals the per-page Python planner's for the same chunk."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.format.enums import Type
-    from parquet_tpu.io.reader import ParquetFile
-    from parquet_tpu.parallel import device_reader as dr
-
-    n = 40_000
-    vals = rng.integers(0, 500, n)
-    for comp, pv in (("snappy", "1.0"), ("zstd", "2.4"), ("none", "1.0")):
-        t = pa.table({"k": pa.array(vals)})
-        buf = io.BytesIO()
-        pq.write_table(t, buf, compression=comp, use_dictionary=True,
-                       data_page_size=4096, version=pv)
-        chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
-        fused, _raw = dr._fused_dict_plan(chunk)
-        assert fused is not None, comp
-        staged = dr.stage_plan(fused)
-        col = dr.decode_staged(chunk.leaf, Type(chunk.meta.type), fused,
-                               staged)
-        got = np.asarray(col.values)
-        if got.dtype == np.uint32:
-            got = got.view(np.int64).reshape(-1)
-        np.testing.assert_array_equal(got, vals)
-
-
-def test_dict_chunk_scan_bails_to_python_on_nulls(lib, rng):
-    """Pages with real nulls are outside the fused fast path: the native
-    scan must bail (return None) and the general planner must handle the
-    chunk — not silently mis-handle validity."""
-    import io
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from parquet_tpu.io.reader import ParquetFile
-    from parquet_tpu.parallel import device_reader as dr
-
-    n = 10_000
-    vals = [None if i % 7 == 0 else int(i % 50) for i in range(n)]
-    t = pa.table({"k": pa.array(vals, type=pa.int64())})
-    buf = io.BytesIO()
-    pq.write_table(t, buf, compression="snappy", use_dictionary=True)
-    chunk = ParquetFile(buf.getvalue()).row_group(0).column(0)
-    fused, raw = dr._fused_dict_plan(chunk)
-    assert fused is None
-    assert raw is not None  # the bail hands the read buffer to the fallback
-    plan = dr.build_plan(chunk)  # falls through to the per-page loop
-    assert plan.total_values < plan.total_slots
 
 
 def test_decompress_pages_rejects_negative_sizes(lib):

@@ -46,7 +46,6 @@ def test_dense_dict_unfused_route(dtype, monkeypatch, rng):
     from parquet_tpu.parallel import device_reader as dr
 
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
-    monkeypatch.setenv("PARQUET_TPU_DICT_RUNS", "device")
     d = (rng.random(32) * 1000).astype(dtype)
     v = d[rng.integers(0, 32, 5000)]
     buf = io.BytesIO()
@@ -129,7 +128,6 @@ def test_injected_unpack_failure_raises_dense_dict(monkeypatch, rng):
     from parquet_tpu.parallel import device_reader as dr
 
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
-    monkeypatch.setenv("PARQUET_TPU_DICT_RUNS", "device")
     monkeypatch.setattr(pk, "unpack_bits_dense", _broken)
     dr._dense_unpack_pages.clear_cache()
     buf = io.BytesIO()
@@ -152,7 +150,6 @@ def test_injected_unpack_failure_raises_delta(monkeypatch, rng):
     from parquet_tpu.parallel import device_reader as dr
 
     monkeypatch.setenv("PARQUET_TPU_PALLAS", "pallas")
-    monkeypatch.setenv("PARQUET_TPU_DELTA_RUNS", "device")
     monkeypatch.setattr(pk, "unpack_bits_dense", _broken)
     dr._delta_decode_dense.clear_cache()
     buf = io.BytesIO()
