@@ -195,3 +195,262 @@ def bloom_check_blocks(blocks: jax.Array, low_bits: jax.Array,
         interpret=interpret,
     )(lanes, low)
     return out[0, :n] != 0
+
+
+# ---------------------------------------------------------------------------
+# Survivor compaction of a device scan: rows whose mask is set move to a
+# prefix, in order, without a scatter or a gather.  XLA lowers a scatter
+# with repeated indices to a serial loop on the TPU (~70 ns a row); here a
+# one-hot placement matrix moves each block's rows on the MXU.
+# ---------------------------------------------------------------------------
+
+#: rows of a block, and the width of the placement matrix: v5e's MXU is 128
+#: wide, and building the (2B, B) matrix costs 2B compares a row
+COMPACT_BLOCK = 128
+#: blocks per grid step (16,384 rows)
+COMPACT_STEP = 128
+#: survivor offsets per SMEM block: a rank-1 s32 array is tiled by 1,024
+#: in HBM, and Mosaic takes rank-1 SMEM blocks only at that tiling
+_COMPACT_OFFS = 1024
+#: word rows per kernel call: a step's input block takes 64 KiB a word row,
+#: twice over (double-buffered), so 32 rows hold it near 4 MiB of v5e's
+#: 16 MiB scoped VMEM whatever the scan's width; wider scans make one call
+#: per group of rows over the same offsets
+COMPACT_WORDS = 32
+
+
+def _scan_compact_kernel(start_ref, end_ref, mask_ref, x_ref, out_ref,
+                         win_ref, stage_ref, sem, nflush_ref, excl_ref, *,
+                         wp: int, row_ids: bool, blocks: int):
+    """One grid step: ``COMPACT_STEP`` blocks of B rows, in order.
+
+    ``x_ref`` holds the step's words as (blocks, wp, B); ``start_ref`` /
+    ``end_ref`` each block's first and one-past-last output slot.  The
+    window (``win_ref``, 2B output rows as four byte planes of f32) holds
+    the output block that begins at ``start & -B``: a block's survivors
+    land there through ``planes @ P.T`` with ``P[j, i] = (j == slot of row
+    i)``.  Bytes are exact in bf16, and each output element has one
+    non-zero term, so the words come back bit for bit.  Once the window's
+    first B rows are full they go to ``out_ref`` (HBM) by DMA, through two
+    staging slots, and the window shifts down by B."""
+    B = COMPACT_BLOCK
+    i32 = jnp.int32
+    t = pl.program_id(0)
+
+    @pl.when(t == 0)
+    def _init():
+        win_ref[...] = jnp.zeros_like(win_ref)
+        nflush_ref[0] = i32(0)
+
+    # exclusive prefix of each block's mask: one matmul with a strictly
+    # upper-triangular ones matrix (exact in f32: sums stay under 2^24)
+    rows = jax.lax.broadcasted_iota(i32, (B, B), 0)
+    cols = jax.lax.broadcasted_iota(i32, (B, B), 1)
+    excl_ref[...] = jnp.dot(
+        mask_ref[...].astype(jnp.float32).astype(jnp.bfloat16),
+        (rows < cols).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)
+    slot = jax.lax.broadcasted_iota(i32, (2 * B, B), 0)
+
+    def copy(k, group):
+        return pltpu.make_async_copy(stage_ref.at[k], out_ref.at[group],
+                                     sem.at[k])
+
+    def flush(base):
+        f = nflush_ref[0]
+        k = f & i32(1)
+
+        @pl.when(f >= 2)
+        def _reuse():  # the slot's previous copy must have landed
+            copy(k, i32(0)).wait()
+
+        b = win_ref[:, :B].astype(i32).astype(jnp.uint32)
+        word = b[0:wp]
+        for p in range(1, 4):
+            word = word | (b[p * wp:(p + 1) * wp] << jnp.uint32(8 * p))
+        stage_ref[k] = word
+        group = jax.lax.shift_right_logical(base, i32(B.bit_length() - 1))
+        copy(k, group).start()
+        win_ref[:, :B] = win_ref[:, B:]
+        win_ref[:, B:] = jnp.zeros((4 * wp, B), jnp.float32)
+        nflush_ref[0] = f + 1
+
+    def block(s, carry):
+        o = (t & i32(_COMPACT_OFFS // COMPACT_STEP - 1)) * COMPACT_STEP + s
+        g0 = start_ref[o]
+        base = g0 & i32(-B)
+        keep = mask_ref[pl.ds(s, 1), :]
+        excl = excl_ref[pl.ds(s, 1), :].astype(i32)
+        tgt = jnp.where(keep != 0, (g0 - base) + excl, i32(-1))  # (1, B)
+        place = (slot == tgt).astype(jnp.bfloat16)  # (2B, B)
+        words = x_ref[s]  # (wp, B)
+        if row_ids:  # the last word row carries each row's index
+            rid = ((t * COMPACT_STEP + s) * B
+                   + jax.lax.broadcasted_iota(i32, (wp, B), 1))
+            last = jax.lax.broadcasted_iota(i32, (wp, B), 0) == wp - 1
+            words = jnp.where(last, rid.astype(jnp.uint32), words)
+        planes = jnp.concatenate(
+            [((words >> jnp.uint32(8 * p)) & jnp.uint32(255)).astype(i32)
+             for p in range(4)], axis=0)
+        win_ref[...] += jax.lax.dot_general(
+            planes.astype(jnp.float32).astype(jnp.bfloat16), place,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+        @pl.when((end_ref[o] & i32(-B)) > base)
+        def _full():
+            flush(base)
+
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(i32(COMPACT_STEP),
+                                     blocks - t * COMPACT_STEP), block, i32(0))
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _last():
+        total = end_ref[(blocks - 1) % _COMPACT_OFFS]
+
+        @pl.when((total & i32(B - 1)) > 0)
+        def _partial():
+            flush(total & i32(-B))
+
+        f = nflush_ref[0]
+
+        @pl.when(f >= 1)
+        def _drain1():
+            copy((f - 1) & i32(1), i32(0)).wait()
+
+        @pl.when(f >= 2)
+        def _drain2():
+            copy(f & i32(1), i32(0)).wait()
+
+
+def _word_rows(a: jax.Array) -> jax.Array:
+    """A compacted array as (n, c) uint32 words: 32-bit values bitcast,
+    bools as 0/1, byte rows (FIXED_LEN_BYTE_ARRAY) padded to whole words."""
+    if a.dtype == jnp.bool_:
+        a = a.astype(jnp.uint32)
+    if a.dtype.itemsize == 1:
+        a = jnp.pad(a, ((0, 0), (0, -a.shape[1] % 4)))
+        a = a.reshape(a.shape[0], -1, 4)
+    w = jax.lax.bitcast_convert_type(a, jnp.uint32)
+    return w.reshape(w.shape[0], -1)
+
+
+def _from_groups(g: jax.Array, like: jax.Array, n: int) -> jax.Array:
+    """(G, c, B) compacted words → ``like``'s dtype and trailing shape."""
+    w = g.transpose(0, 2, 1).reshape(-1, g.shape[1])[:n]
+    if like.dtype == jnp.bool_:
+        return w[:, 0] != 0
+    if like.dtype.itemsize == 1:
+        b = jax.lax.bitcast_convert_type(w, like.dtype).reshape(n, -1)
+        return b[:, :like.shape[1]]
+    out = jax.lax.bitcast_convert_type(w, like.dtype)
+    return out.reshape((n,) + like.shape[1:])
+
+
+def scan_compact_width(arrays) -> int:
+    """W, the uint32 words a row of ``arrays`` takes in :func:`scan_compact`
+    (a row of bytes is padded to whole words)."""
+    return sum(-(-int(np.prod(a.shape[1:], dtype=np.int64))
+                 * a.dtype.itemsize // 4) for a in arrays)
+
+
+@functools.partial(jax.jit, static_argnames=("row_ids", "interpret"))
+def scan_compact(mask: jax.Array, arrays, row_ids: bool = False,
+                 interpret: bool = False):
+    """Move the rows of every array in ``arrays`` whose ``mask`` is set to
+    a prefix, in order, in one pass: ``(count, compacted arrays)``.
+
+    ``arrays``: a tuple of (n,) or (n, c) arrays of 32-bit values, bools or
+    bytes (64-bit columns as (n, 2) uint32 pairs).  Each comes back in its
+    own dtype and shape, n rows long; rows past ``count`` hold no data.
+    With ``row_ids`` a last int32 array holds the surviving rows' indices.
+
+    The arrays travel as W rows of uint32 words in groups of B rows,
+    ``(G, W, B)``: an (n, 2) pair array laid out by the TPU as a lo and a
+    hi row per 128 values is that form already, so no lane-padded
+    transpose is made.  Each group's survivor offsets come from an XLA
+    reduction and prefix over the mask, as scalars for the kernel's DMA
+    addresses; the placement itself runs in :func:`_scan_compact_kernel`,
+    one call per ``COMPACT_WORDS`` word rows (the row ids take a row).
+    """
+    from . import device as dev
+
+    B = COMPACT_BLOCK
+    n = mask.shape[0]
+    arrays = tuple(arrays)
+    count = jnp.sum(mask.astype(jnp.int32), dtype=jnp.int32)
+    if n == 0 or not (arrays or row_ids):
+        return count, arrays + ((jnp.zeros(n, jnp.int32),) if row_ids else ())
+    step = COMPACT_STEP * B
+    npad = -(-n // step) * step
+    G = npad // B
+    blocks = -(-n // B)
+    keep = jnp.pad(mask.astype(jnp.int32), (0, npad - n)).reshape(G, B)
+    per = jnp.pad(jnp.sum(keep, axis=1, dtype=jnp.int32),
+                  (0, -G % _COMPACT_OFFS))
+    end = dev.cumsum(per)
+    start = end - per
+    parts = []
+    for a in arrays:
+        w = _word_rows(a)
+        parts.append(jnp.pad(w, ((0, npad - n), (0, 0)))
+                     .reshape(G, B, w.shape[1]).transpose(0, 2, 1))
+    words = (jnp.concatenate(parts, axis=1) if parts
+             else jnp.zeros((G, 0, B), jnp.uint32))
+    width = words.shape[1]
+
+    steps_per_offs = _COMPACT_OFFS // COMPACT_STEP  # a power of two
+
+    def offs(i):  # a shift: `//` on a traced int32 fails to lower under x64
+        return (jax.lax.shift_right_logical(
+            i, jnp.int32(steps_per_offs.bit_length() - 1)),)
+
+    def call(x, rid):
+        wp = x.shape[1]
+        return pl.pallas_call(
+            functools.partial(_scan_compact_kernel, wp=wp, row_ids=rid,
+                              blocks=blocks),
+            out_shape=jax.ShapeDtypeStruct((G, wp, B), jnp.uint32),
+            grid=(npad // step,),
+            in_specs=[pl.BlockSpec((_COMPACT_OFFS,), offs,
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((_COMPACT_OFFS,), offs,
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((COMPACT_STEP, B), _row_block,
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((COMPACT_STEP, wp, B),
+                                   lambda i: (i, jnp.int32(0), jnp.int32(0)),
+                                   memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((4 * wp, 2 * B), jnp.float32),
+                            pltpu.VMEM((2, wp, B), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((COMPACT_STEP, B), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(start, end, keep, x)
+
+    # groups of at most COMPACT_WORDS word rows, each padded to a multiple
+    # of 8 (a sublane tile); the row ids take the last row of the last group
+    total = width + row_ids
+    done, rid = [], None
+    for r0 in range(0, total, COMPACT_WORDS):
+        r1 = min(r0 + COMPACT_WORDS, total)
+        last = row_ids and r1 == total
+        x = words[:, r0:min(r1, width)]
+        wp = -(-(r1 - r0) // 8) * 8
+        out = call(jnp.pad(x, ((0, 0), (0, wp - x.shape[1]), (0, 0))), last)
+        done.append(out[:, :x.shape[1]])
+        if last:
+            rid = out[:, wp - 1:wp]
+    out = jnp.concatenate(done, axis=1)
+    outs, r = [], 0
+    for a, p in zip(arrays, parts):
+        outs.append(_from_groups(out[:, r:r + p.shape[1]], a, n))
+        r += p.shape[1]
+    if row_ids:
+        outs.append(_from_groups(rid, jnp.zeros((0,), jnp.int32), n))
+    return count, tuple(outs)

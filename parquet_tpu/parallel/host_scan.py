@@ -1020,14 +1020,37 @@ class _ScanCarrier:
         self.flushed = upto
 
 
-def _compact(arr, tgt):
-    """Stable prefix-compaction by scatter: row i lands at tgt[i]; dropped
-    rows target index n (out of bounds, mode='drop').  O(n), an order of
-    magnitude cheaper than the argsort-permutation it replaces (the sort
-    lowers to an O(n log²n) network on TPU)."""
-    import jax.numpy as jnp
+def _span_arrays(forms):
+    """The device arrays of ``forms`` (``{column: (values, validity)}``,
+    values an array or a ``(dictionary, indices)`` pair, validity an array
+    or None) that a span compacts, in :func:`_compact_span`'s order: a
+    dictionary column compacts its indices and keeps its dictionary."""
+    arrays = []
+    for vals, valid in forms.values():
+        arrays.append(vals[1] if isinstance(vals, tuple) else vals)
+        if valid is not None:
+            arrays.append(valid)
+    return arrays
 
-    return jnp.zeros_like(arr).at[tgt].set(arr, mode="drop")
+
+def _compact_span(mask, forms, row_ids: bool):
+    """Compact a span's survivors: one ``pallas_kernels.scan_compact`` pass
+    over :func:`_span_arrays` of ``forms``.  Returns ``(count, outs, vouts,
+    row ids or None)`` in the forms given."""
+    import jax
+
+    from ..ops import pallas_kernels as pk
+
+    cnt, packed = pk.scan_compact(mask, tuple(_span_arrays(forms)),
+                                  row_ids=row_ids,
+                                  interpret=jax.default_backend() != "tpu")
+    got = iter(packed)
+    outs, vouts = {}, {}
+    for c, (vals, valid) in forms.items():
+        outs[c] = ((vals[0], next(got)) if isinstance(vals, tuple)
+                   else next(got))
+        vouts[c] = next(got) if valid is not None else None
+    return cnt, outs, vouts, (next(got) if row_ids else None)
 
 
 class _FlatForm:
@@ -1045,38 +1068,31 @@ class _FlatForm:
 
 
 def _make_fused_span(path, out_cols, per_col, lo, hi, probe, n_rows):
-    """One jitted program for a span's whole filter phase (mask + cumsum +
-    prefix-compaction of every output column).  Eagerly these are ~a dozen
-    separate dispatches of ~100k-element ops, and dispatch overhead — not
-    compute — dominated the device scan (measured 3 ms of 6 ms per span on
-    the config-5 shape).  Built once at stage time; the jit object lives in
-    the staged state, so repeated decoded_scan calls reuse the compile.
+    """One jitted program for a span's whole filter phase (mask, then one
+    ``scan_compact`` pass over every output column).  Eagerly these are
+    ~a dozen separate dispatches of ~100k-element ops, and dispatch
+    overhead — not compute — dominated the device scan (measured 3 ms of
+    6 ms per span on the config-5 shape).  Built once at stage time; the
+    jit object lives in the staged state, so repeated decoded_scan calls
+    reuse the compile.
     Only non-dictionary spans qualify (the dictionary key path folds host
     dictionary entries at trace time via a different route)."""
     import jax
-    import jax.numpy as jnp
-
-    from ..ops import device as dev
 
     key_chunk, key_dplan, _, key_trim = per_col[path]
     key_no_nulls = key_dplan.total_values == key_dplan.total_slots
-    infos = [(c, per_col[c][0], per_col[c][1], per_col[c][3]) for c in out_cols]
+    infos = [(c, per_col[c][1], per_col[c][3]) for c in out_cols]
 
     def run(key_form, col_forms):
         kcol = _FlatForm(*key_form)
         mask = _key_mask_device(key_chunk.leaf, kcol, lo, hi, key_trim,
                                 n_rows, key_no_nulls, values=probe)
-        pos = dev.cumsum(mask.astype(jnp.int32)) - 1
-        tgt = jnp.where(mask, pos, n_rows)
-        outs = {}
-        vouts = {}
-        for c, chunk_c, dplan_c, trim_c in infos:
-            vals, valid = _row_aligned_device(
-                _FlatForm(*col_forms[c]), trim_c, n_rows,
-                no_nulls=dplan_c.total_values == dplan_c.total_slots)
-            outs[c] = _compact(vals, tgt)
-            vouts[c] = _compact(valid, tgt) if valid is not None else None
-        return jnp.sum(mask.astype(jnp.int32)), outs, vouts
+        forms = {c: _row_aligned_device(
+                     _FlatForm(*col_forms[c]), trim_c, n_rows,
+                     no_nulls=dplan_c.total_values == dplan_c.total_slots)
+                 for c, dplan_c, trim_c in infos}
+        cnt, outs, vouts, _ = _compact_span(mask, forms, row_ids=False)
+        return cnt, outs, vouts
 
     return jax.jit(run)
 
@@ -1116,15 +1132,14 @@ class _FusedFactory:
 
 def _scan_dispatch(state, carrier: _ScanCarrier,
                    sync_every: Optional[int] = None) -> None:
-    """Phase A — dispatch with (almost) no syncs: per span, survivors are
-    compacted to a prefix with one cumsum + stable scatter of the predicate
-    mask (device-shape-static; no data-dependent host round-trip per span).
+    """Phase A — dispatch with (almost) no syncs: per span, survivors of
+    every output column are compacted to a prefix in one ``scan_compact``
+    pass (device-shape-static; no data-dependent host round-trip per span).
     With ``sync_every``, counts are synced in batches so device residency
     stays bounded by a few spans' worth of uncompacted output."""
-    import jax.numpy as jnp
-
     from ..format.enums import Type
-    from ..ops import device as dev
+    from ..ops import pallas_kernels as pk
+    from ..utils.debug import counters
     from . import device_reader as dr
 
     path, out_cols = state["path"], state["out_cols"]
@@ -1155,30 +1170,27 @@ def _scan_dispatch(state, carrier: _ScanCarrier,
             no_nulls = dplan.total_values == dplan.total_slots
             mask = _key_mask_device(chunk.leaf, key, lo, hi, trim, n_rows,
                                     no_nulls, values=probe)
-            pos = dev.cumsum(mask.astype(jnp.int32)) - 1
-            tgt = jnp.where(mask, pos, n_rows)  # survivors -> prefix
-            cnt = jnp.sum(mask.astype(jnp.int32))
-            ragged_idx = (_compact(jnp.arange(n_rows, dtype=jnp.int32), tgt)
-                          if ragged_cols else None)
-            outs, vouts = {}, {}
-            for c in out_cols:
-                if per_col[c][0] == "host_ragged":
-                    # survivor ROW indices ride the device; byte gather
-                    # happens host-side at assemble (survivor-only)
-                    _, hv, ho, hvalid = per_col[c]
-                    outs[c] = ("host_ragged", hv, ho, hvalid, ragged_idx)
-                    vouts[c] = None
-                    continue
-                chunk_c, dplan_c, staged_c, trim_c = per_col[c]
-                vals, valid = _row_aligned_device(
-                    cols[c], trim_c, n_rows,
-                    no_nulls=dplan_c.total_values == dplan_c.total_slots)
-                if isinstance(vals, tuple):  # dictionary form: compact indices
-                    dictionary, indices = vals
-                    outs[c] = (dictionary, _compact(indices, tgt))
-                else:
-                    outs[c] = _compact(vals, tgt)
-                vouts[c] = _compact(valid, tgt) if valid is not None else None
+            forms = {c: _row_aligned_device(
+                         col, per_col[c][3], n_rows,
+                         no_nulls=(per_col[c][1].total_values
+                                   == per_col[c][1].total_slots))
+                     for c, col in cols.items()}
+            arrays = _span_arrays(forms)
+            if arrays or ragged_cols:
+                # counted here, where jit_scan_compact runs as its own
+                # program: a fused span inlines the kernel into its jit
+                counters.inc("kernel_bytes.scan_compact",
+                             (4 * pk.scan_compact_width(arrays) + 1)
+                             * mask.shape[0])
+                counters.inc("kernel_runs.scan_compact")
+            cnt, outs, vouts, ragged_idx = _compact_span(
+                mask, forms, row_ids=bool(ragged_cols))
+            for c in ragged_cols:
+                # survivor ROW indices ride the device; byte gather
+                # happens host-side at assemble (survivor-only)
+                _, hv, ho, hvalid = per_col[c]
+                outs[c] = ("host_ragged", hv, ho, hvalid, ragged_idx)
+                vouts[c] = None
         carrier.counts.append(cnt)
         for c in out_cols:
             carrier.parts[c].append(outs[c])

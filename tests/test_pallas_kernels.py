@@ -176,3 +176,100 @@ def test_injected_bloom_kernel_failure_raises(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(_KernelBroke):
         filt.check_hashes_device(hashes)
+
+
+def _compact_inputs(kind, n, rng):
+    """The arrays of each form a device scan compacts (none: row ids only)."""
+    if kind == "int32":
+        return (rng.integers(-2**31, 2**31, n, dtype=np.int64)
+                .astype(np.int32),)
+    if kind == "float32":
+        v = rng.standard_normal(n).astype(np.float32)
+        v[::3] = -0.0
+        v[1::5] = np.frombuffer(np.uint32(0x7FC01234).tobytes(), np.float32)
+        return (v,)
+    if kind in ("float64_pairs", "int64_pairs"):
+        if kind == "float64_pairs":
+            v = rng.standard_normal(n)
+            v[::3] = -0.0
+            v[1::5] = np.frombuffer(np.uint64(0x7FF8000000ABCDEF).tobytes(),
+                                    np.float64)
+        else:
+            v = rng.integers(-2**63, 2**63 - 1, n, dtype=np.int64)
+        return (v.view(np.uint32).reshape(n, 2),)
+    if kind == "validity":
+        return (rng.random(n) < 0.7,)
+    if kind == "dict_indices":
+        return (rng.integers(0, 37, n).astype(np.int32),)
+    if kind == "flba":  # FIXED_LEN_BYTE_ARRAY rows: padded to whole words
+        return tuple(rng.integers(0, 256, (n, L), dtype=np.uint8)
+                     for L in (3, 16))
+    assert kind == "row_ids"
+    return ()
+
+
+def _compact_mask(shape, n, rng):
+    if shape == "all":
+        return np.ones(n, bool)
+    if shape == "none":
+        return np.zeros(n, bool)
+    if shape == "alternating":
+        return np.arange(n) % 2 == 0
+    if shape == "random15":
+        return rng.random(n) < 0.15
+    assert shape == "last_block"
+    m = np.zeros(n, bool)
+    last = (n - 1) // pk.COMPACT_BLOCK * pk.COMPACT_BLOCK
+    m[last:] = rng.random(n - last) < 0.5
+    m[-1] = True
+    return m
+
+
+@pytest.mark.parametrize("n", [
+    100,  # under one block
+    pk.COMPACT_BLOCK,  # exactly one block
+    1000,  # not a multiple of the block: a partial last flush
+    pk.COMPACT_STEP * pk.COMPACT_BLOCK + 300,  # the window crosses a step
+])
+@pytest.mark.parametrize("mask_shape", ["all", "none", "alternating",
+                                        "random15", "last_block"])
+@pytest.mark.parametrize("kind", ["int32", "float32", "float64_pairs",
+                                  "int64_pairs", "validity", "dict_indices",
+                                  "flba", "row_ids"])
+def test_scan_compact_matches_boolean_index(kind, mask_shape, n, rng):
+    """The survivors of every form, bit for bit (NaN payloads and -0.0
+    included), in order, with the count; row ids made in the kernel."""
+    arrays = _compact_inputs(kind, n, rng)
+    mask = _compact_mask(mask_shape, n, rng)
+    _check_compact(mask, arrays, not arrays)
+
+
+def _check_compact(mask, arrays, row_ids):
+    count, outs = pk.scan_compact(mask, arrays, row_ids=row_ids,
+                                  interpret=True)
+    k = int(mask.sum())
+    assert int(count) == k
+    wants = [a[mask] for a in arrays]
+    if row_ids:
+        wants.append(np.nonzero(mask)[0].astype(np.int32))
+    assert len(outs) == len(wants)
+    for got, want in zip(outs, wants):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype
+        assert got.shape == (len(mask),) + want.shape[1:]
+        np.testing.assert_array_equal(got[:k].view(np.uint8),
+                                      want.view(np.uint8))
+
+
+@pytest.mark.parametrize("row_ids", [False, True])
+def test_scan_compact_splits_wide_rows(row_ids, rng):
+    """A scan wider than ``COMPACT_WORDS`` word rows takes one kernel call
+    per group of rows over the same offsets: 44 words (a pair straddles
+    the first group's end), and the row ids in the last group."""
+    n = 1000
+    pairs = [_compact_inputs("float64_pairs", n, rng)[0] for _ in range(20)]
+    arrays = (_compact_inputs("int32", n, rng) + tuple(pairs)
+              + (rng.integers(0, 256, (n, 5), dtype=np.uint8),)
+              + _compact_inputs("validity", n, rng))
+    assert pk.scan_compact_width(arrays) == 44 > pk.COMPACT_WORDS
+    _check_compact(rng.random(n) < 0.15, arrays, row_ids)

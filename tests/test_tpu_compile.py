@@ -174,3 +174,56 @@ def test_prefix_sum_compiles_fast(one_chip):
     mask = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
     bounded(lambda: jax.jit(dev.cumsum).lower(mask).compile(), 15,
             "compiling the blocked prefix sum")
+
+
+Q6_SPAN = 1 << 20  # one lineitem row group of Q6's file
+
+
+def q6_span(sharding, columns=3):
+    """A Q6 span's mask and its 64-bit output columns as (n, 2) uint32
+    pairs (W = 2 a column)."""
+    mask = jax.ShapeDtypeStruct((Q6_SPAN,), jnp.bool_, sharding=sharding)
+    return [mask] + [jax.ShapeDtypeStruct((Q6_SPAN, 2), jnp.uint32,
+                                          sharding=sharding)
+                     for _ in range(columns)]
+
+
+@pytest.mark.parametrize("row_ids", [False, True])
+def test_scan_compact_compiles_without_scatter(row_ids, one_chip):
+    """Q6's span, three 64-bit output columns (W = 6), with and without
+    the row ids a plain string output makes in the kernel.  The eager
+    compaction program is the Mosaic kernel and holds no scatter, which
+    XLA runs as a serial loop on the TPU."""
+    import re
+
+    mask, *pairs = q6_span(one_chip)
+    assert pk.scan_compact_width(pairs) == 6
+    hlo = compile_for(lambda m, *a: pk.scan_compact(m, a, row_ids=row_ids),
+                      mask, *pairs)
+    assert "tpu_custom_call" in hlo
+    assert not re.search(r"\bscatter\(", hlo)
+
+
+def test_scan_compact_compiles_inlined(one_chip):
+    """As a fused span program runs it: the mask made from a key column
+    and the kernel inlined into the caller's jit."""
+    import re
+
+    mask, *pairs = q6_span(one_chip)
+    key = jax.ShapeDtypeStruct((Q6_SPAN,), jnp.int32, sharding=one_chip)
+
+    def span(k, *a):
+        return pk.scan_compact((k >= 8766) & (k < 9131), a)
+
+    hlo = compile_for(span, key, *pairs)
+    assert "tpu_custom_call" in hlo
+    assert not re.search(r"\bscatter\(", hlo)
+
+
+def test_scan_compact_compiles_wide(one_chip):
+    """A scan of 100 64-bit columns (W = 200) stays within VMEM: one kernel
+    call per ``COMPACT_WORDS`` word rows, not one block of all 200."""
+    mask, *pairs = q6_span(one_chip, columns=100)
+    hlo = compile_for(lambda m, *a: pk.scan_compact(m, a, row_ids=True),
+                      mask, *pairs)
+    assert hlo.count("tpu_custom_call") >= -(-201 // pk.COMPACT_WORDS)
